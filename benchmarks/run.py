@@ -30,6 +30,8 @@ def _render(name, rows, note, show=6):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="long simulator runs (more ops)")

@@ -17,6 +17,8 @@ from repro.serving.bench import compare  # noqa: E402
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=16)
     ap.add_argument("--rounds", type=int, default=3)

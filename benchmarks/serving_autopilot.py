@@ -117,6 +117,8 @@ def run_failover(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scenarios", default=",".join(SCENARIOS),
                     help="comma-separated scenario names")

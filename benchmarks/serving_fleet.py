@@ -91,6 +91,8 @@ _SMOKE_CHURN = dict(_SMOKE, sessions=32, kv_mib=0.25)
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hosts", default=None,
                     help=f"comma-separated host counts "

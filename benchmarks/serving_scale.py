@@ -79,6 +79,8 @@ def run_compare(scenarios, *, smoke: bool, seed: int):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--keys", type=int, default=1_000_000,
                     help="control-plane keyspace size")

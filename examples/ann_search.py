@@ -13,15 +13,19 @@ import pathlib
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
+import jax
 import numpy as np
 
 from repro.ann.corpus import make_corpus, make_queries
 from repro.ann.model import AnnWorkload, cpu_sn, gpu_nr, gpu_sn, \
     throughput_kqps
 from repro.ann.progressive import exact_topk, recall_at_k, search
+from repro.kernels import interpret_mode
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=20000)
     ap.add_argument("--queries", type=int, default=200)
@@ -48,7 +52,8 @@ def main():
           f"{stats.stage2_reads} full-vector reads vs "
           f"{stats.stage1_reads} reduced reads")
     print(f"[search] wall: exact {t_exact:.2f}s vs two-stage {t_two:.2f}s "
-          f"(CPU-interpret kernel)")
+          f"({'interpreted' if interpret_mode() else 'compiled'} kernel "
+          f"on {jax.devices()[0].device_kind})")
 
     print("\n[model] 8B-vector corpus, 4 SSDs (paper Fig. 10 geometry):")
     for plat in (gpu_sn(), cpu_sn(), gpu_nr()):

@@ -12,15 +12,24 @@ import pathlib
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
+import jax
 import numpy as np
 
+from repro.kernels import interpret_mode
 from repro.kvstore.cuckoo import BlockedCuckooStore
 from repro.kvstore.model import (KvWorkload, achievable_throughput,
                                  cpu_sn_platform, gpu_nr_platform,
                                  gpu_sn_platform)
 
 
+def _kernel_mode() -> str:
+    how = "interpreted" if interpret_mode() else "compiled"
+    return f"{how} kernel on {jax.devices()[0].device_kind}"
+
+
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     nb, slots = 8192, 8
     st = BlockedCuckooStore(n_buckets=nb, slots=slots,
                             dram_cache_items=1024, wal_limit=128)
@@ -44,7 +53,7 @@ def main():
               == probe[found.astype(bool)] % 99991).sum())
     print(f"[store] batched GET x{len(probe)}: {found.sum()} found, "
           f"{ok} values correct, {dt*1e3:.0f}ms "
-          f"(interpret-mode kernel; ~1.5 block reads/GET)")
+          f"({_kernel_mode()}; ~1.5 block reads/GET)")
     print(f"[store] stats: {st.stats}")
 
     print("\n[model] paper Fig. 8 (5TB store, 80B items, 4 SSDs):")

@@ -98,6 +98,8 @@ def run_advise_tiers(args):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--platform", choices=("cpu", "gpu"), default="gpu")
     ap.add_argument("--l-blk", type=int, default=512)

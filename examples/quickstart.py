@@ -21,6 +21,8 @@ from repro.core import (CPU_DDR, GPU_GDDR, CPU_PLATFORM, GPU_PLATFORM,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ssd = storage_next_ssd(SLC)
     l_blk = 512
 

@@ -32,6 +32,8 @@ from repro.serving.engine import Request
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=ARCHS)
     ap.add_argument("--requests", type=int, default=6)
@@ -64,7 +66,8 @@ def main():
     dt = time.time() - t0
     toks = sum(len(r.generated) for r in done)
     print(f"[serve] {len(done)}/{len(reqs)} requests, {toks} tokens in "
-          f"{dt:.1f}s ({toks/dt:.1f} tok/s on 1 CPU core), "
+          f"{dt:.1f}s ({toks/dt:.1f} tok/s on "
+          f"{jax.devices()[0].device_kind}, compiling included), "
           f"{eng.steps} batched decode steps")
     for r in done[:3]:
         print(f"  {r.rid}: {r.generated}")

@@ -33,6 +33,8 @@ def lm_config(scale: str) -> ModelConfig:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--scale", default="10m", choices=list(SCALES))
     ap.add_argument("--steps", type=int, default=200)

@@ -31,7 +31,10 @@ class SearchStats:
 def exact_topk(queries: np.ndarray, corpus: np.ndarray, k: int):
     d = (np.sum(corpus ** 2, 1)[None, :]
          - 2.0 * queries @ corpus.T)
-    return np.argsort(d, axis=1)[:, :k]
+    # the k nearest in O(N), then ordered by distance among themselves
+    part = np.argpartition(d, k - 1, axis=1)[:, :k]
+    order = np.argsort(np.take_along_axis(d, part, axis=1), axis=1)
+    return np.take_along_axis(part, order, axis=1)
 
 
 def search(queries: np.ndarray, reduced: np.ndarray, full: np.ndarray,

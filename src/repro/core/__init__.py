@@ -1,35 +1,31 @@
 """repro.core — the paper's contribution: a calibrated, constraint- and
 workload-aware reformulation of the five-minute rule (RQ1-RQ3).
 
-Analytics run in float64: enable x64 before any JAX op. Model/runtime code
-elsewhere in the package is dtype-explicit (f32/bf16), so this is safe.
+Analytics run in float64 numpy, so importing them leaves JAX's 32-bit
+default alone: kernels and served programs never see x64 dtypes.
 """
-import jax
-
-jax.config.update("jax_enable_x64", True)
-
-from . import units  # noqa: E402
-from .ssd_model import (  # noqa: E402
+from . import units
+from .ssd_model import (
     NandConfig, SsdConfig, SLC, PSLC, TLC, NAND_TYPES,
     storage_next_ssd, normal_ssd, iops_ssd_peak, iops_dev_peak,
     rw_fractions, gamma_from_mix, bottleneck,
 )
-from .economics import (  # noqa: E402
+from .economics import (
     HostConfig, CPU_DDR, GPU_GDDR, break_even, break_even_components,
     classical_break_even,
 )
-from .constraints import (  # noqa: E402
+from .constraints import (
     mean_read_latency, tail_read_latency, rho_max_for_targets, usable_iops,
     LatencyTargets,
 )
-from .workload import (  # noqa: E402
+from .workload import (
     LogNormalWorkload, EmpiricalWorkload, thresholds, Thresholds,
 )
-from .platform import (  # noqa: E402
+from .platform import (
     PlatformConfig, CPU_PLATFORM, GPU_PLATFORM, analyze_platform,
     PlatformReport,
 )
-from .policy import TieringPolicy, Tier  # noqa: E402
+from .policy import TieringPolicy, Tier
 
 __all__ = [
     "units", "NandConfig", "SsdConfig", "SLC", "PSLC", "TLC", "NAND_TYPES",

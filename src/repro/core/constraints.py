@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
-import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,8 +33,8 @@ class LatencyTargets:
 
 
 def _queue_time(rho, n_ch, iops_peak):
-    service = n_ch / jnp.asarray(iops_peak, jnp.float64)
-    rho = jnp.asarray(rho, jnp.float64)
+    service = n_ch / np.asarray(iops_peak, np.float64)
+    rho = np.asarray(rho, np.float64)
     return service * rho / (2.0 * (1.0 - rho))
 
 
@@ -43,34 +43,34 @@ def mean_read_latency(rho, n_ch, iops_peak, tau_sense):
 
 
 def tail_read_latency(rho, n_ch, iops_peak, tau_sense, p=0.99):
-    k = jnp.log(1.0 / (1.0 - p))
+    k = np.log(1.0 / (1.0 - p))
     return _queue_time(rho, n_ch, iops_peak) * k + tau_sense
 
 
 def _rho_closed_form(tau_hat, tau_sense, service, k):
     """Largest rho with S * rho/(2(1-rho)) * k <= tau_hat - tau_sense."""
-    headroom = jnp.asarray(tau_hat, jnp.float64) - tau_sense
+    headroom = np.asarray(tau_hat, np.float64) - tau_sense
     c = headroom / (service * k)
     rho = 2.0 * c / (1.0 + 2.0 * c)
     # no headroom -> cannot admit load at all
-    return jnp.clip(jnp.where(headroom <= 0.0, 0.0, rho), 0.0, 1.0)
+    return np.clip(np.where(headroom <= 0.0, 0.0, rho), 0.0, 1.0)
 
 
 def rho_max_for_targets(targets: LatencyTargets, n_ch, iops_peak, tau_sense):
     """Largest channel utilization meeting both latency targets."""
-    service = n_ch / jnp.asarray(iops_peak, jnp.float64)
-    rho = jnp.asarray(1.0, jnp.float64)
+    service = n_ch / np.asarray(iops_peak, np.float64)
+    rho = np.asarray(1.0, np.float64)
     if targets.mean is not None:
-        rho = jnp.minimum(rho, _rho_closed_form(
+        rho = np.minimum(rho, _rho_closed_form(
             targets.mean, tau_sense, service, 1.0))
     if targets.tail is not None:
-        k = jnp.log(1.0 / (1.0 - targets.tail_percentile))
-        rho = jnp.minimum(rho, _rho_closed_form(
+        k = np.log(1.0 / (1.0 - targets.tail_percentile))
+        rho = np.minimum(rho, _rho_closed_form(
             targets.tail, tau_sense, service, k))
     return rho
 
 
 def usable_iops(iops_peak, rho_max, iops_proc, n_ssd=1):
     """Feasibility-capped SSD IOPS (paper §IV final expression)."""
-    return jnp.minimum(jnp.asarray(rho_max, jnp.float64) * iops_peak,
-                       jnp.asarray(iops_proc, jnp.float64) / n_ssd)
+    return np.minimum(np.asarray(rho_max, np.float64) * iops_peak,
+                      np.asarray(iops_proc, np.float64) / n_ssd)

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 
-import jax.numpy as jnp
+import numpy as np
 
 from .ssd_model import SsdConfig, iops_ssd_peak
 
@@ -39,12 +39,12 @@ def break_even_components(host: HostConfig, l_blk, ssd_cost, iops_ssd):
     Returns dict with 'host', 'dram_bw', 'ssd' components; their sum is the
     calibrated break-even interval (Eq. 1).
     """
-    l_blk = jnp.asarray(l_blk, dtype=jnp.float64)
+    l_blk = np.asarray(l_blk, dtype=np.float64)
     # $ per I/O for each resource
     c_host_io = host.alpha_core / host.iops_core
     c_dram_io = l_blk * host.alpha_h_dram / host.b_h_dram_die
-    c_ssd_io = jnp.asarray(ssd_cost, jnp.float64) / jnp.asarray(
-        iops_ssd, jnp.float64)
+    c_ssd_io = np.asarray(ssd_cost, np.float64) / np.asarray(
+        iops_ssd, np.float64)
     # DRAM rent rate: $ per second to hold the block resident
     rent_rate = l_blk * host.alpha_h_dram / host.c_h_dram_die
     return {
@@ -79,10 +79,10 @@ def break_even_components_gpu_direct(host: HostConfig, l_blk, ssd_cost,
     Returns {'submit', 'ssd'} components; their sum is tau_be for the
     gpu_flash column.
     """
-    l_blk = jnp.asarray(l_blk, dtype=jnp.float64)
+    l_blk = np.asarray(l_blk, dtype=np.float64)
     c_submit = alpha_submit / iops_submit
-    c_ssd_io = jnp.asarray(ssd_cost, jnp.float64) / jnp.asarray(
-        iops_ssd, jnp.float64)
+    c_ssd_io = np.asarray(ssd_cost, np.float64) / np.asarray(
+        iops_ssd, np.float64)
     rent_rate = l_blk * host.alpha_h_dram / host.c_h_dram_die
     return {
         "submit": c_submit / rent_rate,
@@ -125,7 +125,7 @@ def break_even_components_pool(host: HostConfig, l_blk, *,
         raise ValueError(
             f"rent_factor must be in [0, 1) (got {rent_factor}): at 1.0 "
             "the pool rents at the local-DRAM rate and can never win")
-    l_blk = jnp.asarray(l_blk, dtype=jnp.float64)
+    l_blk = np.asarray(l_blk, dtype=np.float64)
     rent_dram = l_blk * host.alpha_h_dram / host.c_h_dram_die
     rent_saved = rent_dram * (1.0 - rent_factor)
     c_wire = l_blk * alpha_net / pool_bw
@@ -166,10 +166,10 @@ def pool_flash_crossover(host: HostConfig, l_blk, tau_be, *,
     if not 0.0 < rent_factor < 1.0:
         raise ValueError(
             f"rent_factor must be in (0, 1) (got {rent_factor})")
-    l_blk = jnp.asarray(l_blk, dtype=jnp.float64)
+    l_blk = np.asarray(l_blk, dtype=np.float64)
     rent_dram = l_blk * host.alpha_h_dram / host.c_h_dram_die
     c_pool_io = l_blk * alpha_net / pool_bw + alpha_net * pool_rtt
-    return (jnp.asarray(tau_be, jnp.float64)
+    return (np.asarray(tau_be, np.float64)
             - c_pool_io / rent_dram) / rent_factor
 
 
@@ -191,7 +191,7 @@ def classical_break_even(l_blk, ssd_cost, iops_ssd, dram_cost_per_byte):
     With host terms dropped and peak IOPS assumed, Eq. 1 reduces to this.
     dram_cost_per_byte is in the same normalized units as ssd_cost.
     """
-    c_ssd_io = jnp.asarray(ssd_cost, jnp.float64) / jnp.asarray(
-        iops_ssd, jnp.float64)
-    c_dram_page = jnp.asarray(l_blk, jnp.float64) * dram_cost_per_byte
+    c_ssd_io = np.asarray(ssd_cost, np.float64) / np.asarray(
+        iops_ssd, np.float64)
+    c_dram_page = np.asarray(l_blk, np.float64) * dram_cost_per_byte
     return c_ssd_io / c_dram_page

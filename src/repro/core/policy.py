@@ -24,7 +24,7 @@ import enum
 import time
 from typing import Dict, Optional
 
-import jax.numpy as jnp
+import numpy as np
 
 from .constraints import LatencyTargets, rho_max_for_targets, usable_iops
 from .economics import HostConfig, break_even
@@ -82,10 +82,10 @@ class TieringPolicy:
 
     def tiers_for_intervals(self, intervals):
         """Vectorized decision: int8 array of Tier values."""
-        iv = jnp.asarray(intervals)
-        return jnp.where(iv < self.tau_hot, jnp.int8(Tier.HBM),
-                         jnp.where(iv < self.tau_be, jnp.int8(Tier.DRAM),
-                                   jnp.int8(Tier.FLASH)))
+        iv = np.asarray(intervals)
+        return np.where(iv < self.tau_hot, np.int8(Tier.HBM),
+                        np.where(iv < self.tau_be, np.int8(Tier.DRAM),
+                                 np.int8(Tier.FLASH)))
 
     # ---- stateful (EMA + hysteresis) ---------------------------------------
     def observe(self, key, now: Optional[float] = None) -> Tier:

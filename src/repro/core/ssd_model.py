@@ -10,8 +10,8 @@ Peak SSD IOPS is the min of four architectural bounds:
 scaled by the host-visible fraction (Gamma+1)/(Gamma+2*Phi_WA-1) that
 accounts for garbage-collection write amplification competing with host I/O.
 
-Everything is written in jnp so configurations can be swept with jax.vmap;
-plain Python floats work too (weak-typed scalars).
+Everything is written in float64 numpy, so configurations broadcast over
+parameter grids; plain Python floats work too.
 """
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ import dataclasses
 import math
 from typing import Optional
 
-import jax.numpy as jnp
+import numpy as np
 
 from .units import GB, KiB, NS, US, MS
 
@@ -118,21 +118,21 @@ def normal_ssd(nand: NandConfig = SLC, **kw) -> SsdConfig:
 def rw_fractions(gamma_rw, phi_wa):
     """Internal read/write operation fractions (paper §III-B).
 
-    gamma_rw: host read:write ratio (reads per write). May be jnp.inf for
+    gamma_rw: host read:write ratio (reads per write). May be np.inf for
       read-only workloads.
     phi_wa:  intra-SSD write amplification (>= 1).
     Returns (R_r, R_w, host_fraction) where host_fraction =
       (gamma+1)/(gamma+2*phi-1) converts internal op rate to host-visible
       IOPS.
     """
-    gamma_rw = jnp.asarray(gamma_rw, dtype=jnp.float64)
-    phi_wa = jnp.asarray(phi_wa, dtype=jnp.float64)
-    inf = jnp.isinf(gamma_rw)
-    g = jnp.where(inf, 1.0, gamma_rw)  # placeholder to avoid inf arithmetic
+    gamma_rw = np.asarray(gamma_rw, dtype=np.float64)
+    phi_wa = np.asarray(phi_wa, dtype=np.float64)
+    inf = np.isinf(gamma_rw)
+    g = np.where(inf, 1.0, gamma_rw)  # placeholder to avoid inf arithmetic
     denom = g + 2.0 * phi_wa - 1.0
-    r_r = jnp.where(inf, 1.0, (g + phi_wa - 1.0) / denom)
-    r_w = jnp.where(inf, 0.0, phi_wa / denom)
-    host_frac = jnp.where(inf, 1.0, (g + 1.0) / denom)
+    r_r = np.where(inf, 1.0, (g + phi_wa - 1.0) / denom)
+    r_w = np.where(inf, 0.0, phi_wa / denom)
+    host_frac = np.where(inf, 1.0, (g + 1.0) / denom)
     return r_r, r_w, host_frac
 
 
@@ -150,7 +150,7 @@ def gamma_from_mix(read_pct: float, write_pct: float) -> float:
 
 def effective_block(cfg: SsdConfig, l_blk):
     """Internal access size: normal SSDs round sub-4KB up to the codeword."""
-    return jnp.maximum(jnp.asarray(l_blk, jnp.float64), cfg.min_access_bytes)
+    return np.maximum(np.asarray(l_blk, np.float64), cfg.min_access_bytes)
 
 
 def iops_nand_peak(cfg: SsdConfig, l_blk, r_r, r_w):
@@ -179,8 +179,8 @@ def iops_xlat_peak(cfg: SsdConfig):
 
 def iops_pcie_peak(cfg: SsdConfig, l_blk):
     """Interconnect bound: link bandwidth and packet-processing rate (Eq. 3)."""
-    l_blk = jnp.asarray(l_blk, jnp.float64)
-    return jnp.minimum(cfg.b_pcie / l_blk, cfg.pps_host / cfg.pkts_per_io)
+    l_blk = np.asarray(l_blk, np.float64)
+    return np.minimum(cfg.b_pcie / l_blk, cfg.pps_host / cfg.pkts_per_io)
 
 
 def iops_dev_peak(cfg: SsdConfig, l_blk, gamma_rw, phi_wa):
@@ -188,15 +188,15 @@ def iops_dev_peak(cfg: SsdConfig, l_blk, gamma_rw, phi_wa):
     r_r, r_w, host_frac = rw_fractions(gamma_rw, phi_wa)
     per_die = iops_nand_peak(cfg, l_blk, r_r, r_w)
     per_ch = iops_ch_peak(cfg, l_blk, r_r, r_w)
-    internal = cfg.n_ch * jnp.minimum(cfg.n_nand * per_die, per_ch)
+    internal = cfg.n_ch * np.minimum(cfg.n_nand * per_die, per_ch)
     return host_frac * internal
 
 
 def iops_ssd_peak(cfg: SsdConfig, l_blk, gamma_rw=9.0, phi_wa=3.0):
     """Overall peak SSD IOPS (paper Eq. 2)."""
     dev = iops_dev_peak(cfg, l_blk, gamma_rw, phi_wa)
-    return jnp.minimum(jnp.minimum(dev, iops_xlat_peak(cfg)),
-                       iops_pcie_peak(cfg, l_blk))
+    return np.minimum(np.minimum(dev, iops_xlat_peak(cfg)),
+                      iops_pcie_peak(cfg, l_blk))
 
 
 def bottleneck(cfg: SsdConfig, l_blk, gamma_rw=9.0, phi_wa=3.0) -> str:

@@ -24,10 +24,8 @@ import dataclasses
 import math
 from typing import Optional
 
-import jax.numpy as jnp
 import numpy as np
-from jax.scipy.special import ndtri
-from jax.scipy.stats import norm
+from scipy.special import ndtr, ndtri
 
 
 # ---------------------------------------------------------------------------
@@ -65,17 +63,17 @@ class LogNormalWorkload:
 
     def cached_block_fraction(self, T):
         """|S(T)| / N."""
-        x = (jnp.log(jnp.asarray(T, jnp.float64)) - self.mu) / self.sigma
-        return norm.cdf(x)
+        x = (np.log(np.asarray(T, np.float64)) - self.mu) / self.sigma
+        return ndtr(x)
 
     def cached_bytes(self, T):
         return self.cached_block_fraction(T) * self.total_bytes
 
     def psi_c(self, T):
         """Cached (DRAM-served) throughput at threshold T, bytes/s."""
-        x = (jnp.log(jnp.asarray(T, jnp.float64)) - self.mu
+        x = (np.log(np.asarray(T, np.float64)) - self.mu
              + self.sigma ** 2) / self.sigma
-        return self.total_throughput * norm.cdf(x)
+        return self.total_throughput * ndtr(x)
 
     def psi_d(self, T):
         return self.total_throughput - self.psi_c(T)
@@ -87,11 +85,11 @@ class LogNormalWorkload:
     def hit_rate_for_capacity(self, c_dram):
         """Fraction of accesses served from DRAM when the C/l hottest blocks
         are cached: Phi(Phi^{-1}(q) + sigma), q = C / (N l)."""
-        q = jnp.clip(jnp.asarray(c_dram, jnp.float64) / self.total_bytes,
-                     0.0, 1.0)
-        z = ndtri(jnp.clip(q, 1e-300, 1.0 - 1e-16))
-        rate = norm.cdf(z + self.sigma)
-        return jnp.where(q >= 1.0, 1.0, jnp.where(q <= 0.0, 0.0, rate))
+        q = np.clip(np.asarray(c_dram, np.float64) / self.total_bytes,
+                    0.0, 1.0)
+        z = ndtri(np.clip(q, 1e-300, 1.0 - 1e-16))
+        rate = ndtr(z + self.sigma)
+        return np.where(q >= 1.0, 1.0, np.where(q <= 0.0, 0.0, rate))
 
     def capacity_threshold(self, c_dram):
         """T_C: largest T whose cached set fits in c_dram bytes."""
@@ -100,7 +98,7 @@ class LogNormalWorkload:
             return float("inf")
         if q <= 0.0:
             return 0.0
-        return float(jnp.exp(self.mu + self.sigma * ndtri(q)))
+        return float(np.exp(self.mu + self.sigma * ndtri(q)))
 
     def _invert_psi_c(self, target_psi_c) -> float:
         """Smallest T with Psi_c(T) >= target (bytes/s)."""

@@ -8,8 +8,9 @@ running top-k scratch via K rounds of masked arg-min extraction — the full
 [Q, N] distance matrix never touches HBM, which is the point: at
 N = 8B vectors (the paper's corpus) that matrix is unmaterializable.
 
-K is small (<= 64); extraction cost K * bq * (tile + K) flops is noise
-next to the bq x tile x D matmul.
+K is small (<= 64). The extraction rounds run in a loop (bounded VMEM
+whatever K), and a tile whose nearest point loses to every row's
+current k-th best skips the merge — after the first tiles most do.
 """
 from __future__ import annotations
 
@@ -20,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
 
 BIG = 1e30
 
@@ -36,27 +36,54 @@ def _ann_kernel(q_ref, c_ref, od_ref, oi_ref, d_scr, i_scr, *, k: int,
 
     q = q_ref[...].astype(jnp.float32)              # [bq, D]
     c = c_ref[...].astype(jnp.float32)              # [tile, D]
-    # squared L2 = |q|^2 - 2 q.c + |c|^2 ; |q|^2 is rank-constant, dropped
-    dots = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    d = jnp.sum(c * c, axis=1)[None, :] - 2.0 * dots     # [bq, tile]
-    ids = ti * tile + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    d = jnp.where(ids < n_corpus, d, BIG)
+    # squared L2 = |q|^2 - 2 q.c + |c|^2 ; |q|^2 is rank-constant, dropped.
+    # |c|^2 comes off the MXU as a lane-major row: a lane reduction of
+    # c*c would come out sublane-major and its transpose to a [1, tile]
+    # row costs more VMEM than the kernel may use
+    def nt(a, b):                                    # a @ b.T
+        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    c_sq = nt(jnp.ones((8, c.shape[1]), jnp.float32), c * c)[:1]
+    d = c_sq - 2.0 * nt(q, c)                        # [bq, tile]
+    col_t = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    d = jnp.where(ti * tile + col_t < n_corpus, d, BIG)
 
-    # merge into running top-k: concat candidates then extract k minima
-    # via masked arg-min rounds (no scatter -> Mosaic-lowerable)
-    cand_d = jnp.concatenate([d_scr[...], d], axis=1)       # [bq, k+tile]
-    cand_i = jnp.concatenate([i_scr[...], ids], axis=1)
-    col = jax.lax.broadcasted_iota(jnp.int32, cand_d.shape, 1)
-    new_d, new_i = [], []
-    for _ in range(k):
-        am = jnp.argmin(cand_d, axis=1)                     # [bq]
-        sel = col == am[:, None]
-        new_d.append(jnp.min(cand_d, axis=1))
-        new_i.append(jnp.sum(jnp.where(sel, cand_i, 0), axis=1))
-        cand_d = jnp.where(sel, BIG, cand_d)
-    d_scr[...] = jnp.stack(new_d, axis=1)
-    i_scr[...] = jnp.stack(new_i, axis=1).astype(jnp.int32)
+    # a tile enters the running top-k only where it beats a row's k-th
+    # best (the scratch is kept sorted ascending); decided row by row,
+    # so the undefined rows of a partial last query block cannot veto
+    beats = jnp.min(d, axis=1, keepdims=True) < d_scr[:, k - 1:]
+    @pl.when(jnp.max(beats.astype(jnp.int32)) > 0)
+    def _merge():
+        run_d, run_i = d_scr[...], i_scr[...]
+        col_r = jax.lax.broadcasted_iota(jnp.int32, run_d.shape, 1)
+
+        # k rounds of masked arg-min over (running set, tile): each
+        # round moves the smaller of the two minima into output column
+        # r; ties keep the running (lower-id) entry, as a stable merge
+        def round_(r, carry):
+            dt, dr, out_d, out_i = carry
+            mt = jnp.min(dt, axis=1, keepdims=True)          # [bq, 1]
+            mr = jnp.min(dr, axis=1, keepdims=True)
+            pt = jnp.min(jnp.where(dt == mt, col_t, tile), axis=1,
+                         keepdims=True)                      # first arg-min
+            pr = jnp.min(jnp.where(dr == mr, col_r, k), axis=1,
+                         keepdims=True)
+            take_t = mt < mr
+            idr = jnp.sum(jnp.where(col_r == pr, run_i, 0), axis=1,
+                          keepdims=True)
+            best_i = jnp.where(take_t, ti * tile + pt, idr)
+            dt = jnp.where(take_t & (col_t == pt), BIG, dt)
+            dr = jnp.where(~take_t & (col_r == pr), BIG, dr)
+            here = col_r == r
+            out_d = jnp.where(here, jnp.minimum(mt, mr), out_d)
+            out_i = jnp.where(here, best_i, out_i)
+            return dt, dr, out_d, out_i
+
+        _, _, out_d, out_i = jax.lax.fori_loop(
+            0, k, round_, (d, run_d, run_d, run_i))
+        d_scr[...] = out_d
+        i_scr[...] = out_i
 
     @pl.when(ti == n_tiles - 1)
     def _finish():
@@ -65,7 +92,7 @@ def _ann_kernel(q_ref, c_ref, od_ref, oi_ref, d_scr, i_scr, *, k: int,
 
 
 def ann_topk_fwd(queries, corpus, *, k: int = 16, block_q: int = 128,
-                 tile: int = 512, interpret: bool = True):
+                 tile: int = 512, interpret: bool):
     """queries [Q, D]; corpus [N, D] -> (dists [Q, k], ids [Q, k]).
 
     Distances omit the constant |q|^2 term (rank-preserving)."""
@@ -97,6 +124,6 @@ def ann_topk_fwd(queries, corpus, *, k: int = 16, block_q: int = 128,
             pltpu.VMEM((block_q, k), jnp.int32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(queries, corpus)

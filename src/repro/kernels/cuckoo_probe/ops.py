@@ -6,7 +6,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import cuckoo_probe_fwd
+from .. import interpret_mode
+from .kernel import LANES, cuckoo_probe_fwd, lane_dense
+
+# lookups per kernel launch: keys and both bucket-id vectors ride in
+# scalar memory (SMEM), which holds a few tens of KiB
+CHUNK = 2048
 
 
 def hash_pair(keys, n_buckets: int):
@@ -18,11 +23,31 @@ def hash_pair(keys, n_buckets: int):
             (h2 % jnp.uint32(n_buckets)).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def cuckoo_probe(keys, bucket_keys, bucket_vals, *, interpret: bool = True):
+def cuckoo_probe(keys, bucket_keys, bucket_vals):
     """Batched GET. keys [N] int32; table [n_buckets, slots].
 
     Returns (found [N] int32, values [N] int32)."""
-    b1, b2 = hash_pair(keys, bucket_keys.shape[0])
-    return cuckoo_probe_fwd(keys, b1, b2, bucket_keys, bucket_vals,
-                            interpret=interpret)
+    nb, slots = bucket_keys.shape
+    return _probe(jnp.asarray(keys, jnp.int32), lane_dense(bucket_keys),
+                  lane_dense(bucket_vals), n_buckets=nb, slots=slots,
+                  interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("n_buckets", "slots",
+                                             "interpret"))
+def _probe(keys, table_keys, table_vals, *, n_buckets: int, slots: int,
+           interpret: bool):
+    N = keys.shape[0]
+    chunk = min(CHUNK, -(-N // LANES) * LANES)
+    n_chunks = -(-N // chunk)
+    padded = jnp.pad(keys, (0, n_chunks * chunk - N))
+    b1, b2 = hash_pair(padded, n_buckets)
+
+    def one(args):
+        k, i1, i2 = args
+        return cuckoo_probe_fwd(k, i1, i2, table_keys, table_vals,
+                                slots=slots, interpret=interpret)
+
+    found, vals = jax.lax.map(
+        one, tuple(a.reshape(n_chunks, chunk) for a in (padded, b1, b2)))
+    return found.reshape(-1)[:N], vals.reshape(-1)[:N]

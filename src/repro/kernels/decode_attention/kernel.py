@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -76,7 +75,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
 
 def decode_attention_fwd(q, k, v, lengths, *, scale: float,
-                         block_k: int = 512, interpret: bool = True):
+                         block_k: int = 512, interpret: bool):
     """q [B,H,hd]; k,v [B,KV,T,hd]; lengths [B] int32 -> o [B,H,hd]."""
     B, H, hd = q.shape
     KV, T = k.shape[1], k.shape[2]
@@ -105,6 +104,6 @@ def decode_attention_fwd(q, k, v, lengths, *, scale: float,
             pltpu.VMEM((KV, qr, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lengths, q, k, v)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -83,7 +82,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float,
                         block_q: int = 128, block_k: int = 512,
-                        interpret: bool = True):
+                        interpret: bool):
     """q [B,H,S,hd]; k,v [B,KV,T,hd] -> o [B,H,S,hd]."""
     B, H, S, D = q.shape
     KV, T = k.shape[1], k.shape[2]
@@ -117,7 +116,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, scale: float,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(q, k, v)

@@ -12,6 +12,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import flash_attention_fwd
 from .ref import reference_attention
 
@@ -23,10 +24,13 @@ def _pad_head(x, target):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, target - d)])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal: bool = True, scale: float = None,
-                    interpret: bool = True):
+def flash_attention(q, k, v, causal: bool = True, scale: float = None):
     """q [B,H,S,hd]; k,v [B,KV,T,hd] -> [B,H,S,hd]."""
+    return _flash(q, k, v, causal, scale, interpret_mode())
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, scale, interpret):
     return _fwd_impl(q, k, v, causal, scale, interpret)
 
 
@@ -54,4 +58,4 @@ def _bwd_vjp(causal, scale, interpret, res, g):
     return vjp(g)
 
 
-flash_attention.defvjp(_fwd_vjp, _bwd_vjp)
+_flash.defvjp(_fwd_vjp, _bwd_vjp)

@@ -44,7 +44,7 @@ def _sketch_kernel(iv_ref, cls_ref, hist_ref, out_ref, *, tau0: float,
 
 
 def reuse_sketch_fwd(hist, intervals, class_ids, *, tau0: float,
-                     decay: float, interpret: bool = True):
+                     decay: float, interpret: bool):
     """hist [C, B] f32; intervals [N] f32 (<=0 skipped); class_ids [N]
     i32 (rows outside [0, C) skipped). Returns the updated [C, B] hist."""
     C, B = hist.shape

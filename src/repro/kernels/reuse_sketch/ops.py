@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import interpret_mode
 from .kernel import reuse_sketch_fwd
 
 
@@ -19,8 +20,7 @@ def _update(hist, intervals, class_ids, *, tau0, decay, interpret):
 
 
 def reuse_sketch_update(hist, intervals, class_ids, *, tau0: float,
-                        decay: float, batch_pad: int = 256,
-                        interpret: bool = True):
+                        decay: float, batch_pad: int = 256):
     """Decayed sketch update for one step's batch.
 
     hist [C, B] float32; intervals [N] float32 (<= 0 slots skipped);
@@ -47,4 +47,4 @@ def reuse_sketch_update(hist, intervals, class_ids, *, tau0: float,
     cls = np.concatenate([cls, np.full(pad, -1, np.int32)])
     return _update(hist, jnp.asarray(iv), jnp.asarray(cls),
                    tau0=float(tau0), decay=float(decay),
-                   interpret=interpret)
+                   interpret=interpret_mode())

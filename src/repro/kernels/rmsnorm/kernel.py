@@ -21,7 +21,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 128,
-                interpret: bool = True):
+                interpret: bool):
     """x [N, D]; scale [D] -> [N, D]."""
     N, D = x.shape
     block_rows = min(block_rows, N)

@@ -105,8 +105,7 @@ class BlockedCuckooStore:
             import jax.numpy as jnp
             from ..kernels.cuckoo_probe.ops import cuckoo_probe
             f, v = cuckoo_probe(jnp.asarray(keys, jnp.int32),
-                                jnp.asarray(self.keys),
-                                jnp.asarray(self.vals))
+                                self.keys, self.vals)
             return np.asarray(f), np.asarray(v)
         from ..kernels.cuckoo_probe.ref import reference_cuckoo_probe
         import jax.numpy as jnp

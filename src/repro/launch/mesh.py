@@ -11,19 +11,19 @@ module touches no jax device state.
 from __future__ import annotations
 
 import jax
-
-from ..parallel.sharding import make_compat_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh with Auto axis types (tests / small runs)."""
-    return make_compat_mesh(shape, axes)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(*, model: int = 1):

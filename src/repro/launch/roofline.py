@@ -137,17 +137,8 @@ class CostTerms:
                 "wire_by_kind": self.wire_by_kind}
 
 
-def hlo_cost_analysis(compiled) -> dict:
-    """`Compiled.cost_analysis()` returns a per-device dict on newer jax
-    and a one-element list of dicts on older releases; normalize."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def cost_terms(compiled) -> CostTerms:
-    ca = hlo_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     wires = collective_wire_bytes(compiled.as_text())
     return CostTerms(
         flops=float(ca.get("flops", 0.0)),

@@ -409,6 +409,58 @@ def forward(params, cfg: ModelConfig, rules: Rules, batch: Dict[str, Any],
     return _logits(params, cfg, x, ctx), aux
 
 
+def reference_logits(params, cfg: ModelConfig, rules: Rules, tokens,
+                     vocab_chunk: int = 16384):
+    """float32 reference of `forward` that fits beside a served model:
+    the same embedding, group stack and logits, computed in float32 from
+    (say) bf16 `params` cast up one group — and one vocab chunk of the
+    output table — at a time, so the float32 copy of the whole tree is
+    never held. Run it under `jax.default_matmul_precision("highest")`
+    for a full-precision reference. tokens [B,S] -> logits [B,S,V] f32."""
+    if cfg.encoder is not None or cfg.modality == "vlm":
+        raise ValueError("reference_logits covers decoder-only text models")
+    f32 = jnp.float32
+    up = functools.partial(jax.tree.map, lambda a: a.astype(f32))
+    B, S = tokens.shape
+    x = params["embed"][tokens].astype(f32)     # gather, then cast: exact
+    if cfg.embed_scale:
+        x = x * np.sqrt(cfg.d_model)
+    ctx = Ctx(rules=rules, mode="train",
+              positions=_default_positions(cfg, B, S), compute_dtype=f32)
+
+    @jax.jit
+    def group(gp, shared, h):
+        return _apply_group(cfg.pattern, up(gp), up(shared), h, cfg, ctx,
+                            None)[0]
+
+    for i in range(cfg.n_groups):
+        x = group(jax.tree.map(lambda a: a[i], params["groups"]),
+                  params.get("shared", {}), x)
+
+    @jax.jit
+    def tail_and_norm(tail, fn, h):
+        for li, layer in enumerate(cfg.tail):
+            for si, spec in enumerate(layer):
+                h, _ = _sub_apply(up(tail[_key(li, si)]), h, spec, cfg,
+                                  dataclasses.replace(ctx, aux={}))
+        return apply_norm(up(fn), h, cfg.norm, cfg.norm_eps)
+
+    x = tail_and_norm(params.get("tail", {}), params["final_norm"], x)
+
+    @jax.jit
+    def logits(rows, h):
+        out = jnp.einsum("bsd,vd->bsv", h, rows.astype(f32))
+        if cfg.final_logit_softcap:
+            out = jnp.tanh(out / cfg.final_logit_softcap) \
+                * cfg.final_logit_softcap
+        return out
+
+    table = params.get("unembed", params["embed"])
+    return jnp.concatenate(
+        [logits(table[lo:lo + vocab_chunk], x)
+         for lo in range(0, cfg.vocab, vocab_chunk)], axis=-1)
+
+
 def loss_and_aux(params, cfg: ModelConfig, rules: Rules, batch,
                  compute_dtype=jnp.bfloat16, remat: bool = True,
                  remat_policy=None, z_loss: float = 1e-4,

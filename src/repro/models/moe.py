@@ -32,7 +32,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..parallel.sharding import shard_map_compat
 from .config import MoeSpec, ModelConfig
 from .layers import Ctx, dense_init
 from . import ffn as ffn_mod
@@ -227,7 +226,7 @@ def apply(params, x, spec: MoeSpec, cfg: ModelConfig, ctx: Ctx):
     if gated:
         args.append(params["w_gate"])
         in_specs.append(gate_spec)
-    y = shard_map_compat(
+    y = jax.shard_map(
         body, mesh=mesh, in_specs=tuple(in_specs), out_specs=out_specs,
         check_vma=False)(*args)
     y = rules.constrain(y, "batch", None, "res_embed")

@@ -23,8 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .sharding import shard_map_compat
-
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, mesh,
                    axis: str = "stage", n_micro: int = None):
@@ -80,8 +78,8 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, mesh,
     xs = x.reshape(n_micro, mb, *x.shape[1:])
     in_specs = (P(axis), P())        # params split by stage; data replicated
     out_specs = P()
-    y = shard_map_compat(run, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)(
+    y = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)(
         stage_params, xs)
     return y.reshape(B, *x.shape[1:])
 

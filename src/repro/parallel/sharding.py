@@ -17,52 +17,9 @@ import dataclasses
 from typing import Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 AxisName = Union[str, Tuple[str, ...]]
-
-
-def mesh_axis_types_kwargs(n_axes: int) -> dict:
-    """Version-compat shim for `jax.make_mesh(..., axis_types=...)`.
-
-    `jax.sharding.AxisType` (explicit-sharding API) only exists on newer
-    jax; on older releases `jax.make_mesh` neither has nor needs the
-    kwarg — every axis is implicitly Auto. Returns the kwargs dict to
-    splat into `jax.make_mesh`.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_compat_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types on any supported jax version."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **mesh_axis_types_kwargs(len(axes)))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map(..., check_vma=)` on newer jax; falls back to
-    `jax.experimental.shard_map.shard_map(..., check_rep=)` (the same
-    replication check under its earlier name) on older releases."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check_vma)
-
-
-def make_abstract_mesh(shape, axes):
-    """Device-free mesh across the AbstractMesh signature change:
-    newer jax takes (axis_sizes, axis_names); older takes one
-    ((name, size), ...) shape tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,9 +193,12 @@ def serve_rules(mesh: Mesh, *, moe_tokens_gather: bool = False) -> Rules:
     return Rules(mesh=mesh, table=table)
 
 
-def single_device_rules() -> Rules:
-    """Rules over a trivial 1-device mesh — used by smoke tests/examples."""
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+def single_device_rules(device=None) -> Rules:
+    """Rules over a trivial 1x1 mesh on `device` (default: the first
+    device) — one serving replica per chip, smoke tests, examples."""
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=[device or jax.devices()[0]])
     return train_rules(mesh, fsdp=False, shard_residual_embed=False)
 
 

@@ -67,6 +67,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.policy import Tier, TieringPolicy
 from ..models import model as model_lib
@@ -149,14 +150,22 @@ class DecodeEngine:
                  checkpoint_interval: int = 0,
                  compute_dtype=jnp.float32, greedy: bool = True):
         self.cfg = cfg
-        self.params = params
         self.rules = rules
         self.max_slots = max_slots
         self.max_len = max_len
         self.dtype = compute_dtype
         self.greedy = greedy
-        self.cache = model_lib.init_cache(cfg, max_slots, max_len,
-                                          dtype=compute_dtype)
+        # params and caches are committed to the rules' mesh: every
+        # engine's programs see committed arguments on its own device,
+        # so engines on one device share compiled programs and an engine
+        # on another device never computes on (or hops through) device 0
+        placement = NamedSharding(rules.mesh, P())
+        self.params = jax.device_put(params, placement)
+        self._zero_cache = jax.jit(
+            functools.partial(model_lib.init_cache, cfg, max_len=max_len,
+                              dtype=compute_dtype),
+            static_argnums=0, out_shardings=placement)
+        self.cache = self._zero_cache(max_slots)
         self.lengths = np.zeros(max_slots, np.int32)    # filled positions
         self.live = np.zeros(max_slots, bool)
         # parked slots: live (KV resident, slot held) but not decoding —
@@ -260,8 +269,7 @@ class DecodeEngine:
                 tokens = np.concatenate(
                     [req.prompt, np.zeros(L - S, req.prompt.dtype)])
         # run a batch-1 prefill against a temp cache, then splice the slot
-        tmp_cache = model_lib.init_cache(self.cfg, 1, self.max_len,
-                                         dtype=self.dtype)
+        tmp_cache = self._zero_cache(1)
         batch = {"tokens": jnp.asarray(tokens[None, :])}
         if self.cfg.encoder is not None:
             batch["frames"] = jnp.zeros(

@@ -1,14 +1,13 @@
-"""Vectorized analytic sweeps (jax.vmap) over the first-principles model —
-used by the sensitivity benchmarks to sweep large parameter grids cheaply
-and by tests to cross-check the event simulator trends.
+"""Vectorized analytic sweeps over the first-principles model — used by
+the sensitivity benchmarks to sweep large parameter grids cheaply and by
+tests to cross-check the event simulator trends.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Sequence
 
-import jax
-import jax.numpy as jnp
+import numpy as np
 
 from ..core.ssd_model import SsdConfig, iops_ssd_peak
 
@@ -19,13 +18,9 @@ def analytic_iops_grid(cfg: SsdConfig, l_blks: Sequence[int],
 
     Returns array of shape (len(l_blks), len(gammas)).
     """
-    ls = jnp.asarray(l_blks, jnp.float64)
-    gs = jnp.asarray(gammas, jnp.float64)
-
-    def one(l, g):
-        return iops_ssd_peak(cfg, l, g, phi_wa)
-
-    return jax.vmap(lambda l: jax.vmap(lambda g: one(l, g))(gs))(ls)
+    ls = np.asarray(l_blks, np.float64)[:, None]
+    gs = np.asarray(gammas, np.float64)[None, :]
+    return iops_ssd_peak(cfg, ls, gs, phi_wa)
 
 
 def analytic_channel_bw_sweep(cfg: SsdConfig, l_blk: int,
@@ -36,4 +31,4 @@ def analytic_channel_bw_sweep(cfg: SsdConfig, l_blk: int,
     for bw in bws:
         c = dataclasses.replace(cfg, b_ch=float(bw))
         out.append(float(iops_ssd_peak(c, l_blk, gamma, phi_wa)))
-    return jnp.asarray(out)
+    return np.asarray(out)

@@ -282,3 +282,16 @@ class TestWorkload:
                         c_dram=256e9)
         assert th.t_v == max(th.t_b, th.t_s)
         assert isinstance(th.viable, bool)
+
+
+def test_analytics_are_float64_without_enabling_x64():
+    """The analytics keep float64 while JAX stays 32-bit for the whole
+    process: kernels and served programs never see x64 dtypes."""
+    import jax
+    from repro.ssdsim.jaxsweep import analytic_iops_grid
+    assert not jax.config.jax_enable_x64
+    assert np.asarray(break_even(GPU_GDDR, 512, SSD.cost,
+                                 iops_ssd_peak(SSD, 512))).dtype == np.float64
+    grid = analytic_iops_grid(SSD, [512, 4096], [9.0, float("inf")])
+    assert grid.dtype == np.float64 and grid.shape == (2, 2)
+    assert grid[0, 0] == iops_ssd_peak(SSD, 512, 9.0)

@@ -81,3 +81,24 @@ def test_decode_matches_forward(arch, rules):
             np.asarray(logits_dec), np.asarray(logits_par[:, t]),
             rtol=5e-4, atol=5e-4,
             err_msg=f"{arch}: decode step {t} diverged")
+
+
+@pytest.mark.parametrize("arch", ["mistral-nemo-12b", "gemma-2b",
+                                  "qwen3-moe-235b-a22b", "zamba2-7b",
+                                  "xlstm-350m"])
+def test_reference_logits_match_float32_forward(arch, rules):
+    """The layer-at-a-time float32 reference equals `forward` run in
+    float32 on the same bf16-rounded weights."""
+    cfg = _no_drop(get_config(arch, reduced=True))
+    params, _ = M.init_params(jax.random.PRNGKey(0), cfg)
+    bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0,
+                                cfg.vocab)
+    want, _ = M.forward(jax.tree.map(lambda a: a.astype(jnp.float32), bf16),
+                        cfg, rules, {"tokens": tokens},
+                        compute_dtype=jnp.float32, remat=False)
+    got = M.reference_logits(bf16, cfg, rules, tokens,
+                             vocab_chunk=cfg.vocab // 3)
+    assert got.dtype == jnp.float32 and got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)

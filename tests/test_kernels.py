@@ -40,7 +40,7 @@ def test_flash_attention_sweep(B, H, KV, S, D, dtype, causal):
     q = _rand(ks[0], (B, H, S, D), dtype)
     k = _rand(ks[1], (B, KV, S, D), dtype)
     v = _rand(ks[2], (B, KV, S, D), dtype)
-    o = flash_attention(q, k, v, causal, None, True)
+    o = flash_attention(q, k, v, causal, None)
     r = reference_attention(q, k, v, causal=causal, scale=1 / np.sqrt(D))
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(
@@ -55,7 +55,7 @@ def test_flash_attention_grad_matches_reference():
     v = _rand(ks[2], (1, 2, 64, 64), jnp.float32)
 
     def f_kernel(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, None, True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, True, None) ** 2)
 
     def f_ref(q, k, v):
         return jnp.sum(reference_attention(
@@ -131,6 +131,7 @@ def test_rmsnorm_output_rms_is_scale(n, d, scale):
     (64, 1000, 64, 8, 256),
     (100, 2000, 128, 16, 512),
     (16, 300, 32, 4, 128),    # ragged corpus tail
+    (200, 1500, 64, 8, 256),  # partial last query block
 ])
 def test_ann_topk_sweep(Q, N, D, k, tile):
     qs = jax.random.normal(jax.random.PRNGKey(5), (Q, D), jnp.float32)
@@ -192,3 +193,10 @@ def test_cuckoo_probe_sweep(nb, slots, n):
     n_stored = min(128, len(stored))
     assert np.asarray(f)[:n_stored].all()
     assert not np.asarray(f)[len(probe) - 64:].any()
+
+
+def test_interpret_mode_follows_the_backend():
+    """Kernels run interpreted only on the CPU backend; anywhere else a
+    wrapper compiles its kernel."""
+    from repro.kernels import interpret_mode
+    assert interpret_mode() == (jax.default_backend() == "cpu")

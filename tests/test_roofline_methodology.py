@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.roofline import (CostTerms, collective_wire_bytes,
-                                   extrapolate, hlo_cost_analysis, roofline)
+                                   extrapolate, roofline)
 
 
 def test_probe_extrapolation_matches_full_unroll():
@@ -29,7 +29,7 @@ def test_probe_extrapolation_matches_full_unroll():
         xs = jax.ShapeDtypeStruct((32, D), jnp.float32)
         ws = jax.ShapeDtypeStruct((n, D, D), jnp.float32)
         c = jax.jit(step, static_argnames=()).lower(xs, ws).compile()
-        ca = hlo_cost_analysis(c)
+        ca = c.cost_analysis()
         return CostTerms(float(ca.get("flops", 0)),
                          float(ca.get("bytes accessed", 0)), 0.0, {})
 
@@ -95,8 +95,7 @@ NAMES = [None, "batch", "embed", "heads", "kv_heads", "ffn", "experts",
                      max_size=5))
 def test_spec_never_reuses_axis_and_always_divides(dims):
     # AbstractMesh: Rules only reads shape/axis names, no devices needed
-    from repro.parallel.sharding import make_abstract_mesh
-    mesh = make_abstract_mesh((2, 4), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     r = train_rules(mesh)
     shape = [d for d, _ in dims]
     names = [n for _, n in dims]

@@ -83,8 +83,8 @@ def test_elastic_restore_to_different_sharding(tmp_path):
     """Save unsharded, restore with explicit shardings (1-device mesh) —
     the multi-device re-mesh path is exercised in test_distributed.py."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.parallel.sharding import make_compat_mesh
-    mesh = make_compat_mesh((1,), ("x",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("x",))
     mgr = CheckpointManager(CheckpointConfig(root=str(tmp_path)))
     tree = _tree()
     mgr.save(1, tree)
