@@ -222,37 +222,38 @@ def apply(params, x, spec: AttnSpec, cfg: ModelConfig, ctx: Ctx,
         q_pos = pos if pos.ndim == 2 else pos[0]
 
         if cache is not None:
-            if quant:
-                k_w, sk_w = _quantize_kv(k)
-                v_w, sv_w = _quantize_kv(v)
-                writes = {"k": k_w, "v": v_w, "k_scale": sk_w,
-                          "v_scale": sv_w}
-            else:
-                writes = {"k": k.astype(cache["k"].dtype),
-                          "v": v.astype(cache["v"].dtype)}
-            if ctx.mode == "prefill":
-                # static offset 0: plain slice-update keeps sharding
-                new_cache = {
-                    key: jax.lax.dynamic_update_slice(
-                        cache[key], w, (0, 0, 0, 0))
-                    for key, w in writes.items()}
-            else:
-                # decode: select-based write — a dynamic-index
-                # dynamic_update_slice on the (possibly seq-sharded) cache
-                # would force GSPMD to gather the whole cache per step;
-                # where(iota==idx, ...) is elementwise and stays sharded.
-                # cache_index may be scalar or per-slot [B] (continuous
-                # batching).
-                iota = jnp.arange(cache["k"].shape[2])[None, None, :, None]
-                idx_ = jnp.asarray(ctx.cache_index)
-                if idx_.ndim == 1:
-                    idx_ = idx_[:, None, None, None]
-                sel = iota == idx_
-                new_cache = {key: jnp.where(sel, w, cache[key])
-                             for key, w in writes.items()}
-            logi = cache_logical(spec, quantized=quant)
-            new_cache = {key: ctx.rules.constrain(c, *logi[key])
-                         for key, c in new_cache.items()}
+            with jax.named_scope("kv_update"):
+                if quant:
+                    k_w, sk_w = _quantize_kv(k)
+                    v_w, sv_w = _quantize_kv(v)
+                    writes = {"k": k_w, "v": v_w, "k_scale": sk_w,
+                              "v_scale": sv_w}
+                else:
+                    writes = {"k": k.astype(cache["k"].dtype),
+                              "v": v.astype(cache["v"].dtype)}
+                if ctx.mode == "prefill":
+                    # static offset 0: plain slice-update keeps sharding
+                    new_cache = {
+                        key: jax.lax.dynamic_update_slice(
+                            cache[key], w, (0, 0, 0, 0))
+                        for key, w in writes.items()}
+                else:
+                    # decode: select-based write — a dynamic-index
+                    # dynamic_update_slice on the (possibly seq-sharded) cache
+                    # would force GSPMD to gather the whole cache per step;
+                    # where(iota==idx, ...) is elementwise and stays sharded.
+                    # cache_index may be scalar or per-slot [B] (continuous
+                    # batching).
+                    iota = jnp.arange(cache["k"].shape[2])[None, None, :, None]
+                    idx_ = jnp.asarray(ctx.cache_index)
+                    if idx_.ndim == 1:
+                        idx_ = idx_[:, None, None, None]
+                    sel = iota == idx_
+                    new_cache = {key: jnp.where(sel, w, cache[key])
+                                 for key, w in writes.items()}
+                logi = cache_logical(spec, quantized=quant)
+                new_cache = {key: ctx.rules.constrain(c, *logi[key])
+                             for key, c in new_cache.items()}
             k, v = _read_cache(new_cache, dt)
             kv_pos = jnp.arange(k.shape[2])
         else:

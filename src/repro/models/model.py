@@ -74,9 +74,12 @@ def _sub_apply(params, x, spec, cfg: ModelConfig, ctx: Ctx, cache=None):
     h = ctx.rules.constrain(h, "batch", None, "act_embed")
     kind = spec.kind
     if kind == "attn":
-        out, nc = attention.apply(params["mixer"], h, spec, cfg, ctx, cache)
+        with jax.named_scope("attention"):
+            out, nc = attention.apply(params["mixer"], h, spec, cfg, ctx,
+                                      cache)
     elif kind == "ffn":
-        out, nc = ffn.apply(params["mixer"], h, spec, cfg, ctx), None
+        with jax.named_scope("ffn"):
+            out, nc = ffn.apply(params["mixer"], h, spec, cfg, ctx), None
     elif kind == "moe":
         out, nc = moe.apply(params["mixer"], h, spec, cfg, ctx), None
     elif kind == "mamba2":
@@ -313,8 +316,9 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
 
         xs = (params["groups"], gcaches) if gcaches is not None \
             else params["groups"]
-        (x, aux), new_gcaches = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), xs)
+        with jax.named_scope("layers"):
+            (x, aux), new_gcaches = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32)), xs)
 
     new_tail = {}
     tcaches = caches["tail"] if caches else None
@@ -359,9 +363,10 @@ def run_encoder(params, frames, cfg: ModelConfig, ctx: Ctx):
 # ---------------------------------------------------------------------------
 
 def _embed_tokens(params, cfg: ModelConfig, tokens, dtype):
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * np.sqrt(cfg.d_model)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * np.sqrt(cfg.d_model)
     return x
 
 
@@ -521,9 +526,10 @@ def prefill(params, cfg: ModelConfig, rules: Rules, batch, cache,
     else:
         x_last = jax.lax.dynamic_slice_in_dim(
             x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
-    x_last = apply_norm(params["final_norm"], x_last, cfg.norm,
-                        cfg.norm_eps)
-    logits = _logits(params, cfg, x_last, ctx)[:, 0]
+    with jax.named_scope("head"):
+        x_last = apply_norm(params["final_norm"], x_last, cfg.norm,
+                            cfg.norm_eps)
+        logits = _logits(params, cfg, x_last, ctx)[:, 0]
     return new_cache, logits
 
 
@@ -545,6 +551,7 @@ def decode_step(params, cfg: ModelConfig, rules: Rules, token, cache,
     x = rules.constrain(x, "batch", None, "res_embed")
     x, new_cache, _ = run_stack(params, x, cfg, ctx, caches=cache,
                                 unroll=unroll)
-    x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
-    logits = _logits(params, cfg, x, ctx)[:, 0]
+    with jax.named_scope("head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+        logits = _logits(params, cfg, x, ctx)[:, 0]
     return new_cache, logits

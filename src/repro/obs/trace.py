@@ -20,15 +20,42 @@ and seed produces a byte-identical trace file, which CI diffs.
 The tracer is bounded: past `max_events` new events are dropped (and
 counted), never resized — a trace of a 1M-key replay should truncate,
 not OOM.
+
+Wall-clock spans (`span`, `enable_spans`) are the other plane: profiler
+annotations (`repro.<name>`) around the served path's host work, which
+land in the profiler's host plane beside the device's operations, on
+the clock a device trace shares. Off by default; while off a span is
+one shared no-op context.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 from typing import Dict, List, Optional, Tuple
 
 from .jsonio import canon
 
 _US = 1e6    # modeled seconds -> trace microseconds
+
+_SPANS = False
+_OFF = contextlib.nullcontext()
+
+
+def enable_spans(on: bool) -> None:
+    """Turn the served path's wall-clock spans on or off."""
+    global _SPANS
+    _SPANS = bool(on)
+
+
+def span(name: str, **args):
+    """Profiler annotation `repro.<name>` (with `args` as its stats)
+    while spans are on; a shared no-op context while they are off. The
+    annotation is kept in memory until the profiler's trace is written;
+    outside a trace it records nothing."""
+    if not _SPANS:
+        return _OFF
+    import jax      # the modeled plane itself needs no JAX
+    return jax.profiler.TraceAnnotation("repro." + name, **args)
 
 
 class Tracer:

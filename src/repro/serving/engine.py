@@ -56,11 +56,18 @@ advance through pad garbage), so prefill compiles once per bucket
 instead of once per exact length; causal masking keeps real positions
 unaffected and `prefill(last_index=...)` returns the last *real*
 token's logits. `splice_trace_counts()` exposes the retrace counters.
+
+Wall-clock spans (`repro.obs.trace.span`, on only while a profiler
+trace is wanted) mark each phase of `admit`, `step`, `pause` and
+`resume` on the profiler's host plane, so a device trace can put each
+idle gap under the host work that caused it. `counters["d2h_bytes"]`
+counts, always, the bytes the engine copies from the device to the
+host: the step's logits, the first token's logits and the KV blocks
+that pauses and checkpoints read.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
 import warnings
 from typing import Dict, List, Optional
 
@@ -72,6 +79,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.policy import Tier, TieringPolicy
 from ..models import model as model_lib
 from ..models.config import ModelConfig
+from ..obs.trace import span
 from ..parallel.sharding import Rules
 from ..runtime.tiers import TieredStore
 
@@ -161,10 +169,15 @@ class DecodeEngine:
         # on another device never computes on (or hops through) device 0
         placement = NamedSharding(rules.mesh, P())
         self.params = jax.device_put(params, placement)
-        self._zero_cache = jax.jit(
-            functools.partial(model_lib.init_cache, cfg, max_len=max_len,
-                              dtype=compute_dtype),
-            static_argnums=0, out_shardings=placement)
+
+        # named functions, not partials: a device trace shows each
+        # program under its name (`init_cache`, `prefill`, `decode_step`)
+        def init_cache(batch):
+            return model_lib.init_cache(cfg, batch, max_len=max_len,
+                                        dtype=compute_dtype)
+
+        self._zero_cache = jax.jit(init_cache, static_argnums=0,
+                                   out_shardings=placement)
         self.cache = self._zero_cache(max_slots)
         self.lengths = np.zeros(max_slots, np.int32)    # filled positions
         self.live = np.zeros(max_slots, bool)
@@ -213,17 +226,20 @@ class DecodeEngine:
             spec.kind in ("attn", "ffn", "moe")
             for *_ignored, spec in cfg.sublayers())
         self.jit_stats = {"prefill_traces": 0}
+        self.counters = {"d2h_bytes": 0}
 
-        def _counted_prefill(*a, **kw):
+        def prefill(params, batch, cache, last_index=None):
             self.jit_stats["prefill_traces"] += 1
-            return model_lib.prefill(*a, **kw)
+            return model_lib.prefill(params, cfg, rules, batch, cache,
+                                     compute_dtype=compute_dtype,
+                                     last_index=last_index)
 
-        self._prefill = jax.jit(functools.partial(
-            _counted_prefill, cfg=cfg, rules=rules,
-            compute_dtype=compute_dtype))
-        self._decode = jax.jit(functools.partial(
-            model_lib.decode_step, cfg=cfg, rules=rules,
-            compute_dtype=compute_dtype))
+        def decode_step(params, token, cache, index):
+            return model_lib.decode_step(params, cfg, rules, token, cache,
+                                         index, compute_dtype=compute_dtype)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step)
 
     # -------------------------------------------------------- observability
     def _trace_session(self, name: str, rid: str, flow: str = "",
@@ -256,44 +272,52 @@ class DecodeEngine:
         caches): prefill compiles once per bucket, the causal mask keeps
         real positions pad-independent, decode masks positions beyond
         the fill index, and `last_index` picks the real last logits."""
-        free = self._free_slots()
-        if not free:
-            raise RuntimeError("no free slots")
-        slot = free[0]
-        S = len(req.prompt)
-        assert S < self.max_len
-        tokens = req.prompt
-        if self._bucket_prompts:
-            L = min(_next_pow2(S), self.max_len - 1)
-            if L > S:
-                tokens = np.concatenate(
-                    [req.prompt, np.zeros(L - S, req.prompt.dtype)])
-        # run a batch-1 prefill against a temp cache, then splice the slot
-        tmp_cache = self._zero_cache(1)
-        batch = {"tokens": jnp.asarray(tokens[None, :])}
-        if self.cfg.encoder is not None:
-            batch["frames"] = jnp.zeros(
-                (1, self.cfg.encoder.n_frames, self.cfg.d_model),
-                self.dtype)
-        if self._bucket_prompts:
-            tmp_cache, logits = self._prefill(
-                self.params, batch=batch, cache=tmp_cache,
-                last_index=jnp.asarray(S - 1, jnp.int32))
-        else:
-            tmp_cache, logits = self._prefill(self.params, batch=batch,
-                                              cache=tmp_cache)
-        self._splice_slot(tmp_cache, slot)
-        self.lengths[slot] = S
-        self.live[slot] = True
-        self.active[slot] = True
-        req.slot = slot
-        self.slot_req[slot] = req
-        first = int(np.argmax(np.asarray(logits[0]))) if self.greedy else 0
-        req.generated.append(first)
-        self.last_token[slot] = first
-        self._trace_session("admit", req.rid, flow="s", slot=slot,
-                            prompt_len=S)
-        return slot
+        with span("engine.admit", rid=req.rid):
+            free = self._free_slots()
+            if not free:
+                raise RuntimeError("no free slots")
+            slot = free[0]
+            S = len(req.prompt)
+            assert S < self.max_len
+            tokens = req.prompt
+            if self._bucket_prompts:
+                L = min(_next_pow2(S), self.max_len - 1)
+                if L > S:
+                    tokens = np.concatenate(
+                        [req.prompt, np.zeros(L - S, req.prompt.dtype)])
+            # a batch-1 prefill against a temp cache, then splice the slot
+            with span("engine.prefill"):
+                tmp_cache = self._zero_cache(1)
+                batch = {"tokens": jnp.asarray(tokens[None, :])}
+                if self.cfg.encoder is not None:
+                    batch["frames"] = jnp.zeros(
+                        (1, self.cfg.encoder.n_frames, self.cfg.d_model),
+                        self.dtype)
+                if self._bucket_prompts:
+                    tmp_cache, logits = self._prefill(
+                        self.params, batch=batch, cache=tmp_cache,
+                        last_index=jnp.asarray(S - 1, jnp.int32))
+                else:
+                    tmp_cache, logits = self._prefill(
+                        self.params, batch=batch, cache=tmp_cache)
+            with span("engine.splice"):
+                self._splice_slot(tmp_cache, slot)
+            self.lengths[slot] = S
+            self.live[slot] = True
+            self.active[slot] = True
+            req.slot = slot
+            self.slot_req[slot] = req
+            first = 0
+            if self.greedy:
+                with span("engine.first_token"):
+                    row = np.asarray(logits[0])
+                    first = int(np.argmax(row))
+                self.counters["d2h_bytes"] += row.nbytes
+            req.generated.append(first)
+            self.last_token[slot] = first
+            self._trace_session("admit", req.rid, flow="s", slot=slot,
+                                prompt_len=S)
+            return slot
 
     def _splice_slot(self, src_cache, slot: int, src_idx: int = 0):
         # group caches are stacked [G, B, ...] (batch at dim 1); tail
@@ -304,12 +328,15 @@ class DecodeEngine:
             jnp.asarray(src_idx, jnp.int32))
 
     def _extract_slot(self, slot: int):
-        return {
+        blk = {
             "groups": jax.tree.map(lambda a: np.asarray(a[:, slot]),
                                    self.cache["groups"]),
             "tail": jax.tree.map(lambda a: np.asarray(a[slot]),
                                  self.cache["tail"]),
         }
+        self.counters["d2h_bytes"] += sum(
+            a.nbytes for a in jax.tree.leaves(blk))
+        return blk
 
     def _slot_of_rid(self, rid: str) -> int:
         """Slot currently decoding `rid`; KeyError (not a bare
@@ -326,26 +353,29 @@ class DecodeEngine:
     # -------------------------------------------------------------- pausing
     def pause(self, rid: str):
         """Offload a session's KV block through the tiered store."""
-        slot = self._slot_of_rid(rid)
-        req = self.slot_req.pop(slot)
-        blk = self._extract_slot(slot)
-        flat = jax.tree.leaves(blk)
-        blob = np.concatenate([np.asarray(l, np.float32).ravel()
-                               for l in flat])
-        self.store.put(("kv", rid), blob)
-        state = (req, jax.tree.structure(blk),
-                 [(l.shape, l.dtype) for l in flat],
-                 int(self.lengths[slot]))
-        self._paused[rid] = state
-        # a pause is also the freshest durable point for the session
-        self._checkpoints[rid] = state
-        self.live[slot] = False
-        self.active[slot] = False
-        self.lengths[slot] = 0
-        tier = self.store.tier_of(("kv", rid))
-        self._trace_session("pause", rid, flow="t", slot=slot,
-                            tier=getattr(tier, "name", str(tier)))
-        return tier
+        with span("engine.pause", rid=rid):
+            slot = self._slot_of_rid(rid)
+            req = self.slot_req.pop(slot)
+            with span("engine.extract"):
+                blk = self._extract_slot(slot)
+                flat = jax.tree.leaves(blk)
+                blob = np.concatenate([np.asarray(l, np.float32).ravel()
+                                       for l in flat])
+            with span("engine.put"):
+                self.store.put(("kv", rid), blob)
+            state = (req, jax.tree.structure(blk),
+                     [(l.shape, l.dtype) for l in flat],
+                     int(self.lengths[slot]))
+            self._paused[rid] = state
+            # a pause is also the freshest durable point for the session
+            self._checkpoints[rid] = state
+            self.live[slot] = False
+            self.active[slot] = False
+            self.lengths[slot] = 0
+            tier = self.store.tier_of(("kv", rid))
+            self._trace_session("pause", rid, flow="t", slot=slot,
+                                tier=getattr(tier, "name", str(tier)))
+            return tier
 
     def park(self, rid: str) -> int:
         """Idle a live session in place: the slot and its KV stay
@@ -493,42 +523,46 @@ class DecodeEngine:
         if rid not in self._paused:
             raise KeyError(f"session {rid!r} is not paused on this "
                            f"engine")
-        # secure the slot *before* consuming any session state: the
-        # no-free-slots failure must leave the session fully resumable
-        # (metadata in `_paused`, any issued prefetch still pending)
-        free = self._free_slots()
-        if not free:
-            raise RuntimeError("no free slots")
-        slot = free[0]
-        req, treedef, shapes, length = self._paused.pop(rid)
-        pf = self._pending.pop(rid, None)
-        if pf is None:
-            pf = self.store.get_async(("kv", rid))
-        t0 = self.clock.now()
-        blob = pf.wait()
-        stall = self.clock.now() - t0
-        self.kv_stall_time += stall
-        self._trace_session("resume", rid, flow="f", slot=slot,
-                            stall=stall)
-        leaves, off = [], 0
-        for shape, dtype in shapes:
-            n = int(np.prod(shape))
-            leaves.append(np.asarray(
-                blob[off:off + n].reshape(shape), dtype))
-            off += n
-        blk = jax.tree.unflatten(treedef, leaves)
-        # traced-slot splice: repeated (cross-host) resumes reuse one
-        # compiled program regardless of the landing slot
-        self.cache = _splice_block(self.cache, blk,
-                                   jnp.asarray(slot, jnp.int32))
-        self.lengths[slot] = length
-        self.live[slot] = True
-        self.active[slot] = True
-        if req.generated:
-            self.last_token[slot] = req.generated[-1]
-        req.slot = slot
-        self.slot_req[slot] = req
-        return slot
+        with span("engine.resume", rid=rid):
+            # secure the slot *before* consuming any session state: the
+            # no-free-slots failure must leave the session fully
+            # resumable (metadata in `_paused`, any issued prefetch
+            # still pending)
+            free = self._free_slots()
+            if not free:
+                raise RuntimeError("no free slots")
+            slot = free[0]
+            req, treedef, shapes, length = self._paused.pop(rid)
+            pf = self._pending.pop(rid, None)
+            if pf is None:
+                pf = self.store.get_async(("kv", rid))
+            t0 = self.clock.now()
+            with span("engine.wait"):
+                blob = pf.wait()
+            stall = self.clock.now() - t0
+            self.kv_stall_time += stall
+            self._trace_session("resume", rid, flow="f", slot=slot,
+                                stall=stall)
+            with span("engine.restore"):
+                leaves, off = [], 0
+                for shape, dtype in shapes:
+                    n = int(np.prod(shape))
+                    leaves.append(np.asarray(
+                        blob[off:off + n].reshape(shape), dtype))
+                    off += n
+                blk = jax.tree.unflatten(treedef, leaves)
+                # traced-slot splice: repeated (cross-host) resumes
+                # reuse one compiled program regardless of the slot
+                self.cache = _splice_block(self.cache, blk,
+                                           jnp.asarray(slot, jnp.int32))
+            self.lengths[slot] = length
+            self.live[slot] = True
+            self.active[slot] = True
+            if req.generated:
+                self.last_token[slot] = req.generated[-1]
+            req.slot = slot
+            self.slot_req[slot] = req
+            return slot
 
     # ---------------------------------------------------------------- step
     def step(self):
@@ -542,31 +576,38 @@ class DecodeEngine:
         act = self.live & self.active
         if not act.any():
             return
-        idx = jnp.asarray(self.lengths)
-        self.cache, logits = self._decode(
-            self.params, token=jnp.asarray(self.last_token[:, None]),
-            cache=self.cache, index=idx)
-        self.steps += 1
-        if self.step_time:
-            # modeled decode compute overlaps in-flight KV transfers
-            self.store.runtime.advance(self.step_time)
-        nxt = np.argmax(np.asarray(logits), axis=-1).astype(np.int32)
-        self.last_token = np.where(act, nxt, self.last_token)
-        self.lengths[act] += 1
-        for slot, req in list(self.slot_req.items()):
-            if not act[slot]:
-                continue
-            req.generated.append(int(nxt[slot]))
-            if (len(req.generated) >= req.max_new
-                    or self.lengths[slot] >= self.max_len - 1):
-                req.done = True
-                self.live[slot] = False
-                self.active[slot] = False
-                del self.slot_req[slot]
-                self._checkpoints.pop(req.rid, None)
-        if (self.checkpoint_interval and self.live.any()
-                and self.steps % self.checkpoint_interval == 0):
-            self.checkpoint_live()
+        with span("engine.step", step=self.steps):
+            with span("engine.launch"):
+                idx = jnp.asarray(self.lengths)
+                self.cache, logits = self._decode(
+                    self.params, token=jnp.asarray(self.last_token[:, None]),
+                    cache=self.cache, index=idx)
+            self.steps += 1
+            if self.step_time:
+                # modeled decode compute overlaps in-flight KV transfers
+                self.store.runtime.advance(self.step_time)
+            with span("engine.fetch"):
+                host = np.asarray(logits)
+            self.counters["d2h_bytes"] += host.nbytes
+            with span("engine.sample"):
+                nxt = np.argmax(host, axis=-1).astype(np.int32)
+                self.last_token = np.where(act, nxt, self.last_token)
+                self.lengths[act] += 1
+            with span("engine.retire"):
+                for slot, req in list(self.slot_req.items()):
+                    if not act[slot]:
+                        continue
+                    req.generated.append(int(nxt[slot]))
+                    if (len(req.generated) >= req.max_new
+                            or self.lengths[slot] >= self.max_len - 1):
+                        req.done = True
+                        self.live[slot] = False
+                        self.active[slot] = False
+                        del self.slot_req[slot]
+                        self._checkpoints.pop(req.rid, None)
+                if (self.checkpoint_interval and self.live.any()
+                        and self.steps % self.checkpoint_interval == 0):
+                    self.checkpoint_live()
 
     def run(self, requests: List[Request], max_steps: int = 1000):
         """Simple gang scheduler loop: admit as slots free up, decode

@@ -45,6 +45,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from ..obs.ledger import COMPONENTS, StallLedger
+from ..obs.trace import span
 from .engine import DecodeEngine, Request
 
 
@@ -194,9 +195,40 @@ class ContinuousScheduler:
     # --------------------------------------------------------------- tick
     def tick(self):
         """One scheduler step: arrivals -> prefetch -> admission ->
-        decode (or idle clock advance) -> turn boundaries."""
+        decode (or idle clock advance) -> turn boundaries. Each step is
+        a wall-clock span (`scheduler.<step>`) while spans are on."""
         eng = self.engine
-        # 1. arrivals: due turns leave the waiting heap
+        with span("scheduler.tick"):
+            # 1. arrivals: due turns leave the waiting heap
+            with span("scheduler.arrivals"):
+                self._arrivals()
+            # 2. prefetch-led resume for paused sessions nearing their due
+            with span("scheduler.prefetch"):
+                for job in self._paused_jobs():
+                    lead = self._lead_for(job)
+                    if lead > 0 and job.due() - self.now <= lead:
+                        if job.sid not in eng._pending:
+                            eng.prefetch(job.sid)
+                            self.metrics["prefetches"] += 1
+            # 3. admission: fill free slots in EDF order; parked slots
+            # are preempted (offloaded) when the queue is hungry and the
+            # grid is full
+            with span("scheduler.admission"):
+                while self._ready:
+                    if not eng._free_slots() and not self._preempt_parked():
+                        break
+                    _, _, _, job = heapq.heappop(self._ready)
+                    self._admit(job)
+            # 4. decode or idle tick
+            with span("scheduler.decode"):
+                decoding = self._decode_or_idle()
+            # 5. turn boundaries: pause-on-idle / park / retire
+            if decoding:
+                with span("scheduler.boundaries"):
+                    self._turn_boundaries()
+
+    def _arrivals(self):
+        eng = self.engine
         while self._waiting and self._waiting[0][0] <= self.now:
             _, _, job = heapq.heappop(self._waiting)
             if job.state == "parked":
@@ -216,22 +248,11 @@ class ContinuousScheduler:
                                 deadline=job.deadline())
             else:
                 self._push_ready(job)
-        # 2. prefetch-led resume for paused sessions nearing their due
-        for job in self._paused_jobs():
-            lead = self._lead_for(job)
-            if lead > 0 and job.due() - self.now <= lead:
-                if job.sid not in eng._pending:
-                    eng.prefetch(job.sid)
-                    self.metrics["prefetches"] += 1
-        # 3. admission: fill free slots in EDF order; parked slots are
-        # preempted (offloaded) when the queue is hungry and the grid
-        # is full
-        while self._ready:
-            if not eng._free_slots() and not self._preempt_parked():
-                break
-            _, _, _, job = heapq.heappop(self._ready)
-            self._admit(job)
-        # 4. decode or idle tick
+
+    def _decode_or_idle(self) -> int:
+        """One decode step, or an idle clock advance; the tick's slot
+        accounting. Returns the number of slots that decoded."""
+        eng = self.engine
         decoding = int((eng.live & eng.active).sum())
         if decoding:
             eng.step()
@@ -250,9 +271,7 @@ class ContinuousScheduler:
                                 eng.step_time * idle_slots)
         self.metrics["ticks"] += 1
         self.now += 1
-        # 5. turn boundaries: pause-on-idle / park / retire
-        if decoding:
-            self._turn_boundaries()
+        return decoding
 
     def _paused_jobs(self):
         # sid-sorted for deterministic prefetch issue order
