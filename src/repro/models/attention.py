@@ -106,6 +106,29 @@ def _read_cache(cache, dt):
     return cache["k"], cache["v"]
 
 
+def _layer_of(cache, layer):
+    """The [B, ...] slice of group `layer` of a stacked [G, B, ...] cache;
+    the cache itself when `layer` is None."""
+    if layer is None:
+        return cache
+    return {key: jax.lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+            for key, c in cache.items()}
+
+
+def _write_rows(stack, rows, layer, index):
+    """Scatter each slot's new row into a stacked cache, in place:
+    stack [G, B, KV, T, hd]; rows [B, KV, 1, hd]; row (b, g) lands at
+    (layer, b, g, index[b]). An index past T drops its row, as the
+    select's mask does. One hd-wide row per (slot, kv head): scattering
+    [KV, hd] windows makes the TPU's layout pass store the stack with
+    the sequence inside the kv heads, which copies it whole."""
+    B, KV = rows.shape[:2]
+    idx = jnp.broadcast_to(jnp.asarray(index), (B,))
+    return stack.at[layer, jnp.arange(B)[:, None], jnp.arange(KV)[None, :],
+                    idx[:, None]].set(
+        rows[:, :, 0], indices_are_sorted=True, unique_indices=True)
+
+
 # ---------------------------------------------------------------------------
 # Core scaled-dot-product (GQA, no kv repeat materialization)
 # ---------------------------------------------------------------------------
@@ -185,7 +208,8 @@ def apply(params, x, spec: AttnSpec, cfg: ModelConfig, ctx: Ctx,
     if spec.cross:
         src = ctx.enc_out
         if cache is not None and ctx.mode == "decode":
-            k, v = _read_cache(cache, dt)           # projected at prefill
+            # projected at prefill
+            k, v = _read_cache(_layer_of(cache, ctx.layer), dt)
             new_cache = cache
         else:
             k = jnp.einsum("btd,dgk->bgtk", src, params["wk"].astype(dt))
@@ -237,8 +261,17 @@ def apply(params, x, spec: AttnSpec, cfg: ModelConfig, ctx: Ctx,
                         key: jax.lax.dynamic_update_slice(
                             cache[key], w, (0, 0, 0, 0))
                         for key, w in writes.items()}
+                elif ctx.layer is not None:
+                    # decode in place: `cache` is this sublayer's whole
+                    # [G, B, ...] stack, carried through the layer loop
+                    # (and donated by the engine), so only the new rows
+                    # are written
+                    new_cache = {key: _write_rows(cache[key], w, ctx.layer,
+                                                  ctx.cache_index)
+                                 for key, w in writes.items()}
                 else:
-                    # decode: select-based write — a dynamic-index
+                    # decode of a tail cache (outside the layer loop):
+                    # select-based write — a dynamic-index
                     # dynamic_update_slice on the (possibly seq-sharded) cache
                     # would force GSPMD to gather the whole cache per step;
                     # where(iota==idx, ...) is elementwise and stays sharded.
@@ -252,9 +285,10 @@ def apply(params, x, spec: AttnSpec, cfg: ModelConfig, ctx: Ctx,
                     new_cache = {key: jnp.where(sel, w, cache[key])
                                  for key, w in writes.items()}
                 logi = cache_logical(spec, quantized=quant)
-                new_cache = {key: ctx.rules.constrain(c, *logi[key])
+                lead = () if ctx.layer is None else ("layers",)
+                new_cache = {key: ctx.rules.constrain(c, *lead, *logi[key])
                              for key, c in new_cache.items()}
-            k, v = _read_cache(new_cache, dt)
+            k, v = _read_cache(_layer_of(new_cache, ctx.layer), dt)
             kv_pos = jnp.arange(k.shape[2])
         else:
             new_cache = None

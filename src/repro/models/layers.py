@@ -23,6 +23,10 @@ class Ctx:
     attn_chunk: int = 1024            # kv-block size for chunked attention
     compute_dtype: Any = jnp.bfloat16
     cost_exact: bool = False          # unroll inner loops for cost probes
+    # decode in place: the group index (traced or static) whose slice of
+    # each stacked [G, B, ...] attention cache this layer writes and
+    # reads; None when every cache passed in is the layer's own
+    layer: Any = None
     aux: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
 
     def add_aux(self, name: str, value):

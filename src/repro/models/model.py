@@ -277,6 +277,11 @@ def _apply_group(pattern, gparams, shared, x, cfg, ctx: Ctx, gcache):
     return x, new_cache, aux
 
 
+def _attn_keys(cfg: ModelConfig):
+    return [_key(li, si) for li, layer in enumerate(cfg.pattern)
+            for si, s in enumerate(layer) if s.kind == "attn"]
+
+
 def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
               remat: bool = False, remat_policy=None,
               unroll: bool = False):
@@ -284,12 +289,24 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
 
     `unroll=True` replaces the group scan with a python loop — used by the
     roofline cost probes (HLO cost analysis counts a scan body once, so
-    probes compile unrolled G=1 and G=2 stacks and take the marginal)."""
-    shared = params.get("shared", {})
-    gcaches = caches["groups"] if caches else None
+    probes compile unrolled G=1 and G=2 stacks and take the marginal).
 
-    def group_fn(gp, h, gc):
-        return _apply_group(cfg.pattern, gp, shared, h, cfg, ctx, gc)
+    A decode step carries the stacked attention caches through the layer
+    loop as state instead of scanning over them: each layer scatters its
+    new rows into the stack and reads its slice back, so no layer's cache
+    is copied, sharded or not. Recurrent states are still sliced per
+    layer and stacked back."""
+    shared = params.get("shared", {})
+    gcaches = dict(caches["groups"]) if caches else {}
+    stack = {}
+    if ctx.mode == "decode" and caches:
+        stack = {k: gcaches.pop(k) for k in _attn_keys(cfg)}
+
+    def group_fn(gp, h, gc, stk, layer):
+        lctx = ctx if layer is None else dataclasses.replace(ctx, layer=layer)
+        h, nc, aux = _apply_group(cfg.pattern, gp, shared, h, cfg, lctx,
+                                  {**gc, **stk})
+        return h, {k: nc.pop(k) for k in stk}, nc, aux
 
     wrapped = jax.checkpoint(group_fn, policy=remat_policy) if remat \
         else group_fn
@@ -299,26 +316,24 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
         ncs = []
         for i in range(cfg.n_groups):
             gp = jax.tree.map(lambda a: a[i], params["groups"])
-            gc = (jax.tree.map(lambda a: a[i], gcaches)
-                  if gcaches is not None else None)
-            x, nc, aux_d = wrapped(gp, x, gc)
+            gc = jax.tree.map(lambda a: a[i], gcaches)
+            x, stack, nc, aux_d = wrapped(gp, x, gc, stack,
+                                          i if stack else None)
             aux = aux + aux_d
             ncs.append(nc)
-        new_gcaches = (jax.tree.map(lambda *a: jnp.stack(a), *ncs)
-                       if gcaches is not None else None)
+        new_gcaches = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
     else:
         def body(carry, xs):
-            h, aux = carry
-            gp = xs[0] if gcaches is not None else xs
-            gc = xs[1] if gcaches is not None else None
-            h, nc, aux_d = wrapped(gp, h, gc)
-            return (h, aux + aux_d), nc
+            h, aux, stk = carry
+            gp, gc, layer = xs
+            h, stk, nc, aux_d = wrapped(gp, h, gc, stk, layer)
+            return (h, aux + aux_d, stk), nc
 
-        xs = (params["groups"], gcaches) if gcaches is not None \
-            else params["groups"]
+        xs = (params["groups"], gcaches,
+              jnp.arange(cfg.n_groups) if stack else None)
         with jax.named_scope("layers"):
-            (x, aux), new_gcaches = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)), xs)
+            (x, aux, stack), new_gcaches = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.float32), stack), xs)
 
     new_tail = {}
     tcaches = caches["tail"] if caches else None
@@ -334,7 +349,7 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
             if nc is not None:
                 new_tail[k] = nc
 
-    new_caches = ({"groups": new_gcaches, "tail": new_tail}
+    new_caches = ({"groups": {**new_gcaches, **stack}, "tail": new_tail}
                   if caches is not None else None)
     return x, new_caches, aux
 

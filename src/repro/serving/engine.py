@@ -239,7 +239,9 @@ class DecodeEngine:
                                          index, compute_dtype=compute_dtype)
 
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode_step)
+        # the step consumes the cache it is given: written in place, the
+        # new K/V rows are all it moves (`step` rebinds `self.cache`)
+        self._decode = jax.jit(decode_step, donate_argnames="cache")
 
     # -------------------------------------------------------- observability
     def _trace_session(self, name: str, rid: str, flow: str = "",
