@@ -5,7 +5,9 @@ Everything that belongs to one configuration, traffic mix or metric is
 found by name under the checkout's `bench/` directory:
 
   BENCHMARK.json                 cells, metrics, configuration files
-  bench/configs/<config>.json    sizes, serving geometry, source
+  bench/configs/<config>.json    sizes, serving geometry, source, "arch"
+  bench/arch/<arch>.py           layout, float32 forward, work counts and
+                                 the program's ModelConfig
   bench/traffic/<mix>.json       parameters of the one generator
   bench/metrics/<metric>.py      `read(run) -> float | None`
   bench/limits/<cell>.json       the limit of each number compared
@@ -19,6 +21,12 @@ returns (that call ends on the host, so the token is there). It keeps
 the logits that the timed path produced for a set of requests drawn
 from the seed, and compares them, once the window has closed, with the
 benchmark's float32 reference (`bench/reference.py`).
+
+A traced run (`--trace 1`) also turns the program's own spans on for
+the window (`repro.obs.trace.enable_spans`), lines them up with the
+device trace (`bench/spans.py`), and takes the change of each of the
+engine's counters over the window, for the per-layer readers. An
+untraced run does neither.
 """
 from __future__ import annotations
 
@@ -26,6 +34,7 @@ import contextlib
 import dataclasses
 import functools
 import gc
+import glob
 import importlib.util
 import json
 import math
@@ -36,11 +45,11 @@ import sys
 import tempfile
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import reference, xplane
+from bench import arch, reference, spans, xplane
 from bench.traffic import generator
 
 # the numbers compared for `correct`, each against its limit
@@ -65,8 +74,14 @@ class Bench:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
 
     def config(self, name: str) -> dict:
+        """The configuration file's dict, with the directory its `"arch"`
+        is found in (`arch_dir`: this root's bench/arch); an unknown
+        architecture is an error here."""
         entry = next(c for c in self.spec["configs"] if c["name"] == name)
-        return json.loads((self.root / entry["file"]).read_text())
+        cfg = json.loads((self.root / entry["file"]).read_text())
+        cfg["arch_dir"] = str(self._file("arch"))
+        arch.of(cfg)
+        return cfg
 
     def traffic(self, name: str) -> dict:
         return json.loads(self._file("traffic", f"{name}.json").read_text())
@@ -120,6 +135,11 @@ class Run:
     setup_s: float
     device_kind: str
     trace: Optional[dict]             # reduced device trace
+    # traced runs only: the program's spans lined up with the device
+    # trace (bench/spans.py `reduce`), and the change of each of the
+    # engine's counters over the window
+    program_spans: Optional[dict] = None
+    engine_counters: Optional[Dict[str, int]] = None
 
     def in_window(self, t: float) -> bool:
         return self.t_open <= t <= self.t_close
@@ -194,7 +214,9 @@ class Probe:
     stamped when it returns. It also wraps the engine's prefill and
     decode programs to keep the logits rows they produce for the
     requests in `keep` (the decode step's rows come from the host copy
-    that the engine itself made, so keeping them moves nothing more)."""
+    that the engine itself made, so keeping them moves nothing more).
+    Kept rows are copied into rows that `reserve` wrote before the
+    window, so that the window maps no new memory for them."""
 
     def __init__(self, engine, annotate: bool):
         import jax
@@ -206,12 +228,31 @@ class Probe:
         self.keep: set = set()
         self.rows: Dict[str, list] = {}
         self._logits = None
+        self._pool = np.empty((0, 0))
+        self._used = 0
         self._annotation = jax.profiler.TraceAnnotation if annotate \
             else None
         for name in ("_prefill", "_decode"):
             setattr(engine, name, self._capture(getattr(engine, name)))
         for name in ("admit", "step", "pause", "resume"):
             setattr(engine, name, self._wrap(name, getattr(engine, name)))
+
+    def reserve(self, rows: int):
+        """Rows for the kept logits, of the width and type of the last
+        logits the programs produced, each written once now."""
+        like = np.asarray(self._logits)
+        self._pool = np.empty((rows, like.shape[-1]), like.dtype)
+        self._pool.fill(0)
+        self._used = 0
+
+    def _kept_row(self, row) -> np.ndarray:
+        """`row`, copied into the next reserved row where one is left."""
+        if self._used == len(self._pool) or row.dtype != self._pool.dtype:
+            return row.copy()
+        out = self._pool[self._used]
+        self._used += 1
+        out[...] = row
+        return out
 
     def region(self, name: str):
         if self._annotation is None:
@@ -244,13 +285,14 @@ class Probe:
                 self.admits[rid] = t0
                 self.stamps.setdefault(rid, []).append(t1)
                 if rid in self.keep:
-                    self.rows[rid] = [np.asarray(self._logits)[0].copy()]
+                    self.rows[rid] = [
+                        self._kept_row(np.asarray(self._logits)[0])]
             elif name == "step" and lengths:
                 self.steps.append((t0, t1, lengths))
                 kept = [(s, r) for s, r in reqs if r.rid in self.keep]
                 host = np.asarray(self._logits) if kept else None
                 for s, req in kept:
-                    self.rows[req.rid].append(host[s].copy())
+                    self.rows[req.rid].append(self._kept_row(host[s]))
                 for _, req in reqs:
                     got = self.stamps.setdefault(req.rid, [])
                     got += [t1] * (len(req.generated) - len(got))
@@ -301,22 +343,6 @@ def say(msg: str):
 
 
 # -------------------------------------------------------------- set-up
-def program_config(cfg: dict):
-    """The program's ModelConfig for a configuration file: the
-    registry's architecture at the file's sizes and norm epsilon."""
-    from repro.configs import get_config
-    base = get_config(cfg["registry"])
-    (attn, ffn), = base.pattern
-    pattern = ((dataclasses.replace(
-        attn, n_heads=cfg["num_attention_heads"],
-        n_kv=cfg["num_key_value_heads"], head_dim=cfg["head_dim"]),
-        dataclasses.replace(ffn, d_ff=cfg["intermediate_size"])),)
-    return dataclasses.replace(
-        base, d_model=cfg["hidden_size"], vocab=cfg["vocab_size"],
-        n_groups=cfg["num_hidden_layers"], pattern=pattern,
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"])
-
-
 def build(cfg: dict, seed: int, device):
     """Weights from the seed, and the scheduler over a fresh engine."""
     import jax.numpy as jnp
@@ -332,7 +358,8 @@ def build(cfg: dict, seed: int, device):
                                  tau_be=sv["tau_be_s"], ema_alpha=1.0),
         step_time=sv["step_time_s"])
     sched = Platform.compile(spec).scheduler(
-        program_config(cfg), params, single_device_rules(device),
+        arch.of(cfg).program_config(cfg), params,
+        single_device_rules(device),
         max_slots=sv["slots"], max_len=sv["max_len"],
         compute_dtype=jnp.dtype(sv["dtype"]),
         pause_idle_steps=sv["pause_idle_steps"])
@@ -495,6 +522,18 @@ def as_control(res: dict, precision: str) -> dict:
 
 
 # ----------------------------------------------------------------- run
+def reduce_trace(directory: str) -> Tuple[Optional[dict], Optional[dict]]:
+    """The newest trace the profiler wrote under `directory`, reduced
+    (`xplane.reduce`), and the program's spans lined up with its device
+    (`spans.reduce`); None for each without a device plane."""
+    found = sorted(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    reduced = xplane.reduce(found[-1]) if found else None
+    if reduced is None:
+        return None, None
+    return reduced, spans.reduce(found[-1], reduced["offset_s"])
+
+
 def measure(bench: Bench, workload: str, seed: int, seconds: float,
             trace: bool) -> dict:
     """Set-up and the measured window of one cell on the first device
@@ -502,6 +541,7 @@ def measure(bench: Bench, workload: str, seed: int, seconds: float,
     the sample for the check stay in the result."""
     t_start = process_start()
     import jax
+    from repro.obs.trace import enable_spans
     cell = bench.cell(workload)
     devices = jax.devices()
     dev = devices[0]
@@ -519,26 +559,34 @@ def measure(bench: Bench, workload: str, seed: int, seconds: float,
     keep = kept_sessions(sessions, np.random.default_rng([seed, 1]),
                          seconds, limits["sample_tokens"])
     probe.keep = set(keep)
+    probe.reserve(sum(s.new_tokens for s in sessions if s.sid in probe.keep))
     before = dict(sched.metrics)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
+    engine_counters = dict(sched.engine.counters) if trace else None
     if trace:
         jax.profiler.start_trace(trace_dir)
     setup_s = time.time() - t_start
     compiles.count, compiles.counting = 0, True
-    records, tick_walls, t_open, t_close = window(
-        sched, sessions, seconds, sv["step_time_s"], probe)
+    enable_spans(trace)
+    try:
+        records, tick_walls, t_open, t_close = window(
+            sched, sessions, seconds, sv["step_time_s"], probe)
+    finally:
+        enable_spans(False)
     compiles.counting = False
-    reduced = None
+    reduced = program_spans = None
     if trace:
         jax.profiler.stop_trace()
-        reduced = xplane.reduce_dir(trace_dir)
+        engine_counters = {k: v - engine_counters.get(k, 0)
+                           for k, v in sched.engine.counters.items()}
+        reduced, program_spans = reduce_trace(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
     stats = dev.memory_stats() or {}
     res = dict(
         records=records,
         run=Run(workload, cfg, mix, seconds, t_open, t_close, records,
                 tick_walls, probe.spans, probe.steps, setup_s,
-                dev.device_kind, reduced),
+                dev.device_kind, reduced, program_spans, engine_counters),
         counters={k: v - before.get(k, 0)
                   for k, v in sched.metrics.items()},
         compiles=compiles.count,
@@ -621,6 +669,9 @@ def report(bench: Bench, res: dict, trace: bool) -> dict:
     marks = np.arange(run_.t_open, run_.t_close + 1e-9, run_.seconds / 5)
     say("[window] admission queue at each fifth of the window: "
         + " ".join(str(run_.queue_at(t)) for t in marks[1:]))
+    if run_.program_spans is not None:
+        say("[trace] device idle under each program span, s: "
+            + spans.split_line(run_.program_spans["totals"]))
     for c in res["checks"]:
         say(f"[check] {c['sid']}: prompt {c['prompt']}, served "
             f"{c['served']}, logit_err {c['logit_err']:.6f}, "

@@ -1,12 +1,12 @@
-"""Plain float32 reference of the served decoder, and the weights it
-shares with the program.
+"""Plain float32 reference of the served model, and the weights it shares
+with the program.
 
 It imports nothing of the program. The weights are made here, from the
 seed, in the layout the program's `init_params` gives (checked by a
-test), and handed to the program; the reference reads the same bf16
-values and computes in float32 at `highest` matmul precision, one layer
-at a time and attention in blocks of queries, so it fits beside the
-weights on one chip.
+test), and handed to the program; the reference reads the same values
+and computes in float32 at `highest` matmul precision. What depends on
+the architecture (the parameter layout, the forward pass) is in
+`bench/arch/<arch>.py`, named by the configuration's `"arch"` key.
 
 `precision="int8"` or `"fp8"` computes the same forward with every
 projection's weights (per output channel) and inputs (per token)
@@ -15,14 +15,6 @@ correctness check has to reject.
 
 `compare` holds the program's logits at each served position against
 the reference's, a block of rows and of the vocabulary at a time.
-
-The architecture (as in each configuration's `architecture` line): token
-embedding; per layer x += Attn(RMSNorm(x)), x += SwiGLU(RMSNorm(x));
-final RMSNorm; untied output head. Attention: q/k/v projections, RoPE on
-q and k with rotate-half pairs and inverse frequencies
-theta^(-2i/head_dim), causal softmax(q k^T / sqrt(head_dim)) v with each
-group of n_heads / n_kv query heads sharing one K/V head, output
-projection. SwiGLU: w_out(silu(x w_gate) * (x w_in)).
 """
 from __future__ import annotations
 
@@ -32,47 +24,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import arch
+
 F32 = jnp.float32
 Q_BLOCK = 512          # query rows per attention block
 VOCAB_BLOCK = 16384    # output-head columns per block
 ROW_BLOCK = 256        # hidden rows per output-head call
 
 
-def dims(cfg: dict):
-    return (cfg["hidden_size"], cfg["num_attention_heads"],
-            cfg["num_key_value_heads"], cfg["head_dim"],
-            cfg["intermediate_size"], cfg["num_hidden_layers"],
-            cfg["vocab_size"])
-
-
 # --------------------------------------------------------------- weights
-def param_shapes(cfg: dict) -> dict:
-    """Leaf shapes, in the program's parameter layout: layers stacked on
-    a leading axis; sublayer 0 is attention, sublayer 1 the FFN."""
-    d, h, kv, hd, f, n, v = dims(cfg)
-    return {
-        "embed": (v, d), "unembed": (v, d),
-        "groups": {
-            "L0S0": {"norm": {"scale": (n, d)},
-                     "mixer": {"wq": (n, d, h, hd), "wk": (n, d, kv, hd),
-                               "wv": (n, d, kv, hd), "wo": (n, h, hd, d)}},
-            "L0S1": {"norm": {"scale": (n, d)},
-                     "mixer": {"w_in": (n, d, f), "w_gate": (n, d, f),
-                               "w_out": (n, f, d)}}},
-        "final_norm": {"scale": (d,)},
-    }
-
-
-def _fan_in(path: str, shape) -> int:
-    if path.endswith("wo"):
-        return shape[1] * shape[2]
-    return shape[1]
-
-
 def init_params(seed: int, cfg: dict, dtype=jnp.bfloat16, device=None):
     """All weights from `seed` in one jitted call, made on `device` in
-    `dtype`: each leaf is drawn and cast in one fused pass."""
-    shapes = param_shapes(cfg)
+    `dtype`: each leaf of the architecture's layout is drawn and cast in
+    one fused pass."""
+    mod = arch.of(cfg)
+    shapes = mod.param_shapes(cfg)
     flat, treedef = jax.tree.flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple)
         and all(isinstance(e, int) for e in s))
@@ -86,7 +52,7 @@ def init_params(seed: int, cfg: dict, dtype=jnp.bfloat16, device=None):
                 a = jax.random.uniform(k, shape, F32, 0.5, 1.5)
             else:
                 std = 0.02 if "embed" in name else \
-                    1.0 / np.sqrt(_fan_in(name, shape))
+                    1.0 / np.sqrt(mod.fan_in(name, shape))
                 a = jax.random.truncated_normal(k, -2.0, 2.0, shape,
                                                 F32) * std
             out.append(a.astype(dtype))
@@ -124,85 +90,11 @@ def _rms(x, scale, eps):
         * scale.astype(F32)
 
 
-def _rope(x, theta):
-    """x [P, heads, hd] at positions 0..P-1."""
-    p, _, hd = x.shape
-    half = hd // 2
-    inv = theta ** (-jnp.arange(half, dtype=F32) * 2.0 / hd)
-    ang = jnp.arange(p, dtype=F32)[:, None] * inv[None]
-    sin, cos = jnp.sin(ang)[:, None], jnp.cos(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
 # --------------------------------------------------------------- forward
-@functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
-def _layer(lp, x, cfg_items, precision):
-    cfg = dict(cfg_items)
-    d, h, kv, hd, f, _, _ = dims(cfg)
-    eps, theta = cfg["rms_norm_eps"], cfg["rope_theta"]
-    p = x.shape[0]
-    a, m = lp["L0S0"], lp["L0S1"]
-    hn = _rms(x, a["norm"]["scale"], eps)
-    q = _rope(_mm("pd,dhk->phk", hn, a["mixer"]["wq"], precision,
-                  (1,), (0,)), theta)
-    k = _rope(_mm("pd,dgk->pgk", hn, a["mixer"]["wk"], precision,
-                  (1,), (0,)), theta)
-    v = _mm("pd,dgk->pgk", hn, a["mixer"]["wv"], precision, (1,), (0,))
-    r = h // kv
-    nb = p // Q_BLOCK
-    qb = q.reshape(nb, Q_BLOCK, kv, r, hd)
-    kpos = jnp.arange(p)
-
-    def block(args):
-        qi, i = args
-        s = jnp.einsum("qgrk,tgk->grqt", qi, k) / np.sqrt(hd)
-        qpos = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        s = jnp.where(qpos[:, None] >= kpos[None, :], s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("grqt,tgk->qgrk", w, v)
-
-    o = jax.lax.map(block, (qb, jnp.arange(nb))).reshape(p, h, hd)
-    x = x + _mm("phk,hkd->pd", o, a["mixer"]["wo"], precision,
-                (1, 2), (0, 1))
-    hn = _rms(x, m["norm"]["scale"], eps)
-    g = _mm("pd,df->pf", hn, m["mixer"]["w_gate"], precision, (1,), (0,))
-    u = _mm("pd,df->pf", hn, m["mixer"]["w_in"], precision, (1,), (0,))
-    return x + _mm("pf,fd->pd", jax.nn.silu(g) * u, m["mixer"]["w_out"],
-                   precision, (1,), (0,))
-
-
-@jax.jit
-def _embed_rows(table, tokens):
-    return table[tokens].astype(F32)
-
-
-@functools.partial(jax.jit, static_argnames=("eps",))
-def _final(scale, x, eps):
-    return _rms(x, scale, eps)
-
-
 def bucket(n: int) -> int:
     """Padded sequence length: a power of two, at least one query
     block, so each length compiles once."""
     return max(Q_BLOCK, 1 << max(0, n - 1).bit_length())
-
-
-def hidden(params, cfg: dict, tokens: np.ndarray, precision: str = "f32"):
-    """Final normed hidden states [P, d] for `tokens` right-padded to
-    `bucket(len(tokens))` (causal, so padding changes no real row)."""
-    p = bucket(len(tokens))
-    tok = np.zeros(p, np.int32)
-    tok[:len(tokens)] = tokens
-    items = tuple(sorted((k, v) for k, v in cfg.items()
-                         if isinstance(v, (int, float, str))))
-    with jax.default_matmul_precision("highest"):
-        x = _embed_rows(params["embed"], jnp.asarray(tok))
-        for i in range(cfg["num_hidden_layers"]):
-            lp = jax.tree.map(lambda a: a[i], params["groups"])
-            x = _layer(lp, x, items, precision)
-        return _final(params["final_norm"]["scale"], x,
-                      cfg["rms_norm_eps"])
 
 
 @functools.partial(jax.jit, static_argnames=("precision",))
@@ -254,13 +146,14 @@ def compare(params, cfg: dict, prompt, served, program_rows,
     seq = np.concatenate([prompt, served[:-1]])
     pick = slice(s - 1, s - 1 + n)
     pad = -n % ROW_BLOCK
-    table = params["unembed"]
-    h = jnp.pad(hidden(params, cfg, seq)[pick], ((0, pad), (0, 0)))
+    mod = arch.of(cfg)
+    table = mod.output_table(params)
+    h = jnp.pad(mod.hidden(params, cfg, seq)[pick], ((0, pad), (0, 0)))
     prog = np.pad(np.asarray(program_rows), ((0, pad), (0, 0)))
     tok = np.pad(np.stack([served, (served + 1) % cfg["vocab_size"]], -1),
                  ((0, pad), (0, 0)))
-    hc = {c: jnp.pad(hidden(params, cfg, seq, c)[pick], ((0, pad), (0, 0)))
-          for c in controls}
+    hc = {c: jnp.pad(mod.hidden(params, cfg, seq, c)[pick],
+                     ((0, pad), (0, 0))) for c in controls}
     parts = {k: [] for k in ("program", *controls)}
     with jax.default_matmul_precision("highest"):
         for r0 in range(0, n + pad, ROW_BLOCK):

@@ -9,7 +9,7 @@ gives each span instance its device-idle seconds: the part of its
 interval, clipped to `bench.window`, in which no operation ran. Its
 self idle leaves out what its child spans cover, so the self idle of
 all spans adds up to no more than the window's idle time.
-`bench/idle_split.py` runs a cell with the spans on and reads them.
+The harness reduces them in every traced run (`harness.reduce_trace`).
 """
 from __future__ import annotations
 

@@ -5,7 +5,7 @@ import pathlib
 
 import pytest
 
-from bench import counts
+from bench import arch, counts
 from bench.tests import tiny
 
 CONFIGS = pathlib.Path(__file__).resolve().parents[1] / "configs"
@@ -19,9 +19,9 @@ def test_nemo_decode_step_by_hand():
     cfg = config("mistral-nemo-12b")
     # one layer: wq 5120*4096 + wk, wv 2*5120*1024 + wo 4096*5120
     # + SwiGLU 3*5120*14336 + two norms 2*5120
-    assert counts.layer_params(cfg) == (20_971_520 + 10_485_760
-                                        + 20_971_520 + 220_200_960
-                                        + 10_240) == 272_640_000
+    assert arch.of(cfg).layer_params(cfg) == (
+        20_971_520 + 10_485_760 + 20_971_520 + 220_200_960
+        + 10_240) == 272_640_000
     work = counts.decode_step(cfg, [1000] * 8)
     # matmul weights per token: 8 layers without norms + head 5120*131072
     # = 2_181_038_080 + 671_088_640; x2 FLOPs x8 slots; attention
@@ -39,8 +39,8 @@ def test_nemo_decode_step_by_hand():
 def test_deepseek_decode_step_by_hand():
     cfg = tiny.MHA
     # MHA: q, k, v, o all 4096*4096; SwiGLU 3*4096*11008; norms 2*4096
-    assert counts.layer_params(cfg) == (67_108_864 + 135_266_304
-                                        + 8_192) == 202_383_360
+    assert arch.of(cfg).layer_params(cfg) == (
+        67_108_864 + 135_266_304 + 8_192) == 202_383_360
     work = counts.decode_step(cfg, [2000])
     # 2 * (6 * 202_375_168 + 4096 * 102400) + 6 * 4 * 32 * 128 * 2001
     assert work["flops"] == 3_267_362_816 + 196_706_304

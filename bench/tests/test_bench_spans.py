@@ -3,10 +3,12 @@ hand-made intervals: nested spans, a gap that straddles a span's end,
 spans clipped to the window, and the numbers read from them; and on a
 small trace recorded on a TPU v5e."""
 import pathlib
+import shutil
 
 import pytest
 
-from bench import spans, xplane
+from bench import harness, spans, xplane
+from bench.tests import tiny
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 
@@ -121,3 +123,50 @@ def test_recorded_chip_trace_puts_idle_under_its_span():
                                            for t in tot.values())
     assert uncovered == pytest.approx(idle["pace"] + idle["harness"],
                                       rel=0.01)
+
+
+def test_the_harness_reduces_the_spans_of_its_trace(tmp_path):
+    """`harness.reduce_trace` on a profiler directory that holds the
+    recorded trace gives the reduction above, and its step idle."""
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    shutil.copy(DATA / "spans.xplane.pb", d / "host.xplane.pb")
+    reduced, got = harness.reduce_trace(str(tmp_path))
+    path = str(DATA / "spans.xplane.pb")
+    assert reduced == xplane.reduce(path)
+    assert got == spans.reduce(path, reduced["offset_s"])
+    tot = got["totals"]
+    assert tot["engine.step"]["count"] == 5
+    assert tot["engine.sample"]["self_idle_s"] == pytest.approx(0.020,
+                                                                rel=0.3)
+    # each step: the 6 ms of sleep in sample and retire, and the host's
+    # share of the launch and the fetch
+    assert 6 <= spans.step_idle_ms(got["instances"]) < 12
+    # the tick's own: 3 ms after the step
+    assert spans.tick_idle_ms(got["instances"]) == pytest.approx(3, rel=0.3)
+
+
+@pytest.mark.parametrize("trace", [True, False], ids=["traced", "untraced"])
+def test_only_a_traced_run_reads_the_programs_counters(tmp_path, trace):
+    """A traced run puts the engine's transfer counter over the window
+    on the result line (`d2h_kb_per_token`); an untraced one reads
+    neither the counters nor the spans. On the CPU there is no device
+    plane, so no span metric."""
+    from repro.obs import trace as obs
+    root = tiny.make_root(tmp_path)
+    res = harness.run(root, "tiny.chat", 2**31 + 11, 1.0, trace)
+    run = res["run"]
+    assert not obs._SPANS
+    assert run.program_spans is None
+    line = harness.result_line(harness.Bench(root), res, trace)
+    if trace:
+        assert run.engine_counters["d2h_bytes"] > 0
+        got = line["metrics"]["d2h_kb_per_token"]
+        assert got["unit"] == "KiB/token"
+        assert got["value"] == pytest.approx(
+            run.engine_counters["d2h_bytes"] / run.tokens_in_window()
+            / 1024)
+        assert "step_idle_ms" not in line["metrics"]
+    else:
+        assert run.engine_counters is None
+        assert "d2h_kb_per_token" not in line["metrics"]

@@ -6,7 +6,7 @@ import pathlib
 
 import pytest
 
-from bench import xplane
+from bench import harness, xplane
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 
@@ -54,4 +54,4 @@ def test_recorded_chip_trace():
 
 
 def test_no_device_plane_reduces_to_nothing(tmp_path):
-    assert xplane.reduce_dir(str(tmp_path)) is None
+    assert harness.reduce_trace(str(tmp_path)) == (None, None)

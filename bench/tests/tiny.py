@@ -1,6 +1,7 @@
 """A benchmark root at a size the CPU runs in seconds: a two-layer
 model of the served architecture, two small mixes, and the benchmark's
-own metric readers, written under a temporary directory."""
+own metric readers and architectures, written under a temporary
+directory."""
 from __future__ import annotations
 
 import json
@@ -10,7 +11,7 @@ import shutil
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 CONFIG = {
-    "name": "tiny", "registry": "mistral-nemo-12b",
+    "name": "tiny", "registry": "mistral-nemo-12b", "arch": "dense",
     "hidden_size": 128, "num_hidden_layers": 2, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 192,
     "vocab_size": 4096, "rope_theta": 10000.0, "rms_norm_eps": 1e-5,
@@ -33,7 +34,7 @@ MIXES = {
 # MHA at deepseek-7b's published widths (hf:deepseek-ai/deepseek-llm-7b-base),
 # for the tests that cover both attention layouts
 MHA = {
-    "name": "deepseek-7b", "registry": "deepseek-7b",
+    "name": "deepseek-7b", "registry": "deepseek-7b", "arch": "dense",
     "hidden_size": 4096, "num_hidden_layers": 6, "num_attention_heads": 32,
     "num_key_value_heads": 32, "head_dim": 128, "intermediate_size": 11008,
     "vocab_size": 102400, "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
@@ -56,7 +57,9 @@ def make_root(tmp: pathlib.Path) -> pathlib.Path:
     real = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
     root = pathlib.Path(tmp)
     b = root / "bench"
-    shutil.copytree(BENCH / "metrics", b / "metrics")
+    for d in ("metrics", "arch"):
+        shutil.copytree(BENCH / d, b / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     for d in ("configs", "traffic", "limits"):
         (b / d).mkdir(parents=True)
     (b / "configs" / "tiny.json").write_text(json.dumps(CONFIG))

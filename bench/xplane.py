@@ -12,8 +12,6 @@ decode step is what `bench.step` launches. The measured window is the
 """
 from __future__ import annotations
 
-import glob
-import os
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
 
@@ -176,10 +174,3 @@ def reduce(path: str, device: int = 0) -> Optional[dict]:
                                  for k, v in runs.items()},
             "top_ops": [[k, v] for k, v in top],
             "idle_by_host": [[k, v * 1e-9] for k, v in idle_top]}
-
-
-def reduce_dir(directory: str) -> Optional[dict]:
-    """Reduce the newest trace the profiler wrote under `directory`."""
-    found = sorted(glob.glob(os.path.join(directory, "**", "*.xplane.pb"),
-                             recursive=True), key=os.path.getmtime)
-    return reduce(found[-1]) if found else None
