@@ -42,8 +42,8 @@ def init(key, cfg: ModelConfig, spec: AttnSpec):
                          fan_in=spec.n_heads * spec.head_dim),
     }
     if spec.qk_norm:
-        params["q_scale"] = jnp.ones((spec.head_dim,), jnp.float32)
-        params["k_scale"] = jnp.ones((spec.head_dim,), jnp.float32)
+        params["q_norm"] = jnp.ones((spec.head_dim,), jnp.float32)
+        params["k_norm"] = jnp.ones((spec.head_dim,), jnp.float32)
     return params, logical(cfg, spec)
 
 
@@ -55,8 +55,8 @@ def logical(cfg: ModelConfig, spec: AttnSpec):
         "wo": ("heads", "head_dim", "embed"),
     }
     if spec.qk_norm:
-        out["q_scale"] = ("head_dim",)
-        out["k_scale"] = ("head_dim",)
+        out["q_norm"] = ("head_dim",)
+        out["k_norm"] = ("head_dim",)
     return out
 
 
@@ -230,9 +230,9 @@ def apply(params, x, spec: AttnSpec, cfg: ModelConfig, ctx: Ctx,
         k = jnp.einsum("bsd,dgk->bgsk", x, params["wk"].astype(dt))
         v = jnp.einsum("bsd,dgk->bgsk", x, params["wv"].astype(dt))
         if spec.qk_norm:
-            q = rms_norm_heads(q, params["q_scale"], cfg.norm_eps)
+            q = rms_norm_heads(q, params["q_norm"], cfg.norm_eps)
             k = rms_norm_heads(
-                k.transpose(0, 2, 1, 3), params["k_scale"],
+                k.transpose(0, 2, 1, 3), params["k_norm"],
                 cfg.norm_eps).transpose(0, 2, 1, 3)
         pos = ctx.positions
         if spec.rope == "rope":

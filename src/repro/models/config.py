@@ -11,7 +11,8 @@ Sublayer kinds:
   AttnSpec    multi-head attention (GQA/MQA/MHA, RoPE or M-RoPE, optional
               cross-attention and cross-stack weight sharing)
   FfnSpec     dense gated/plain MLP
-  MoeSpec     mixture-of-experts with top-k routing + static capacity
+  MoeSpec     mixture-of-experts with top-k routing over every expert; a
+              layer may hold a contiguous range of them (expert parallelism)
   Mamba2Spec  Mamba-2 state-space duality block (chunked scan)
   MLstmSpec   xLSTM matrix-memory block (chunked parallel form)
   SLstmSpec   xLSTM scalar-memory block (sequential recurrence)
@@ -58,18 +59,27 @@ class FfnSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MoeSpec:
-    n_experts: int
+    n_experts: int                   # experts the router scores
     top_k: int
     d_ff: int
     act: str = "swiglu"
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training's dispatch only
     shared_d_ff: int = 0             # always-on shared expert (llama4)
     aux_loss_weight: float = 0.01
     router_dtype: str = "float32"
+    # the experts this layer holds: [first, first + n_held) of the
+    # n_experts routed over; 0 holds them all. Served tokens routed to
+    # an expert not held get no part of the output from it
+    first: int = 0
+    n_held: int = 0
 
     @property
     def kind(self) -> str:
         return "moe"
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,6 +172,10 @@ class ModelConfig:
         return any(s.kind == "attn" for _, _, _, s in self.sublayers())
 
     @property
+    def has_moe(self) -> bool:
+        return any(s.kind == "moe" for _, _, _, s in self.sublayers())
+
+    @property
     def sub_quadratic(self) -> bool:
         """True when decode state does not grow O(seq) per full-attn layer —
         SSM / linear-attention families. Determines long_500k eligibility."""
@@ -190,7 +204,7 @@ class ModelConfig:
                 return mult * d * s.d_ff
             if s.kind == "moe":
                 mult = 3 if s.act in ("swiglu", "geglu") else 2
-                n_ = s.n_experts * mult * d * s.d_ff + d * s.n_experts
+                n_ = s.held * mult * d * s.d_ff + d * s.n_experts
                 if s.shared_d_ff:
                     n_ += mult * d * s.shared_d_ff
                 return n_
@@ -242,6 +256,6 @@ class ModelConfig:
                 continue
             reps = self.n_groups if where == "pattern" else 1
             mult = 3 if s.act in ("swiglu", "geglu") else 2
-            inactive = (s.n_experts - s.top_k) * mult * d * s.d_ff
+            inactive = max(s.held - s.top_k, 0) * mult * d * s.d_ff
             n -= reps * inactive
         return int(n)

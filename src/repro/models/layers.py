@@ -27,10 +27,19 @@ class Ctx:
     # each stacked [G, B, ...] attention cache this layer writes and
     # reads; None when every cache passed in is the layer's own
     layer: Any = None
+    # serving: [B,S] bool, the tokens whose work is wanted (a decode
+    # step's decoding slots, a prefill's real positions); None = all
+    token_mask: Optional[jax.Array] = None
     aux: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
+    # int32 counts of the work done (the MoE layer's routing), summed
+    # over layers by `run_stack`
+    counts: Dict[str, jax.Array] = dataclasses.field(default_factory=dict)
 
     def add_aux(self, name: str, value):
         self.aux[name] = self.aux.get(name, 0.0) + value
+
+    def add_count(self, name: str, value):
+        self.counts[name] = self.counts.get(name, 0) + value
 
 
 # ---------------------------------------------------------------------------

@@ -262,8 +262,9 @@ def cache_logical_tree(cfg: ModelConfig, kv_quant: bool = False):
 # ---------------------------------------------------------------------------
 
 def _apply_group(pattern, gparams, shared, x, cfg, ctx: Ctx, gcache):
+    """Returns (x, new_cache, aux_loss, counts of the group's work)."""
     new_cache = {}
-    ctx = dataclasses.replace(ctx, aux={})
+    ctx = dataclasses.replace(ctx, aux={}, counts={})
     for li, layer in enumerate(pattern):
         for si, spec in enumerate(layer):
             k = _key(li, si)
@@ -274,7 +275,7 @@ def _apply_group(pattern, gparams, shared, x, cfg, ctx: Ctx, gcache):
                 new_cache[k] = nc
     x = ctx.rules.constrain(x, "batch", None, "res_embed")
     aux = functools.reduce(jnp.add, ctx.aux.values(), jnp.zeros((), jnp.float32))
-    return x, new_cache, aux
+    return x, new_cache, aux, ctx.counts
 
 
 def _attn_keys(cfg: ModelConfig):
@@ -295,7 +296,10 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
     loop as state instead of scanning over them: each layer scatters its
     new rows into the stack and reads its slice back, so no layer's cache
     is copied, sharded or not. Recurrent states are still sliced per
-    layer and stacked back."""
+    layer and stacked back.
+
+    What the layers count of their work (`Ctx.add_count`) is summed
+    over the stack into `ctx.counts`."""
     shared = params.get("shared", {})
     gcaches = dict(caches["groups"]) if caches else {}
     stack = {}
@@ -304,36 +308,40 @@ def run_stack(params, x, cfg: ModelConfig, ctx: Ctx, caches=None,
 
     def group_fn(gp, h, gc, stk, layer):
         lctx = ctx if layer is None else dataclasses.replace(ctx, layer=layer)
-        h, nc, aux = _apply_group(cfg.pattern, gp, shared, h, cfg, lctx,
-                                  {**gc, **stk})
-        return h, {k: nc.pop(k) for k in stk}, nc, aux
+        h, nc, aux, counts = _apply_group(cfg.pattern, gp, shared, h, cfg,
+                                          lctx, {**gc, **stk})
+        return h, {k: nc.pop(k) for k in stk}, nc, aux, counts
 
     wrapped = jax.checkpoint(group_fn, policy=remat_policy) if remat \
         else group_fn
 
     if unroll:
         aux = jnp.zeros((), jnp.float32)
-        ncs = []
+        ncs, counts = [], []
         for i in range(cfg.n_groups):
             gp = jax.tree.map(lambda a: a[i], params["groups"])
             gc = jax.tree.map(lambda a: a[i], gcaches)
-            x, stack, nc, aux_d = wrapped(gp, x, gc, stack,
-                                          i if stack else None)
+            x, stack, nc, aux_d, cnt = wrapped(gp, x, gc, stack,
+                                               i if stack else None)
             aux = aux + aux_d
             ncs.append(nc)
+            counts.append(cnt)
         new_gcaches = jax.tree.map(lambda *a: jnp.stack(a), *ncs)
+        counts = jax.tree.map(lambda *a: jnp.stack(a), *counts)
     else:
         def body(carry, xs):
             h, aux, stk = carry
             gp, gc, layer = xs
-            h, stk, nc, aux_d = wrapped(gp, h, gc, stk, layer)
-            return (h, aux + aux_d, stk), nc
+            h, stk, nc, aux_d, cnt = wrapped(gp, h, gc, stk, layer)
+            return (h, aux + aux_d, stk), (nc, cnt)
 
         xs = (params["groups"], gcaches,
               jnp.arange(cfg.n_groups) if stack else None)
         with jax.named_scope("layers"):
-            (x, aux, stack), new_gcaches = jax.lax.scan(
+            (x, aux, stack), (new_gcaches, counts) = jax.lax.scan(
                 body, (x, jnp.zeros((), jnp.float32), stack), xs)
+    for k, v in counts.items():
+        ctx.add_count(k, v.sum(0))
 
     new_tail = {}
     tcaches = caches["tail"] if caches else None
@@ -365,7 +373,7 @@ def run_encoder(params, frames, cfg: ModelConfig, ctx: Ctx):
         aux={})
 
     def body(h, gp):
-        h, _, _ = _apply_group(enc.pattern, gp, {}, h, cfg, ectx, None)
+        h = _apply_group(enc.pattern, gp, {}, h, cfg, ectx, None)[0]
         return h, None
 
     x, _ = jax.lax.scan(body, x, params["encoder"]["groups"])
@@ -517,7 +525,8 @@ def prefill(params, cfg: ModelConfig, rules: Rules, batch, cache,
     return instead of the final one — the serving engine right-pads
     prompts to power-of-two buckets (one compile per bucket instead of
     one per exact length) and still needs the logits of the last *real*
-    token; causality keeps positions < last_index unaffected by pads."""
+    token; causality keeps positions < last_index unaffected by pads,
+    and the MoE layers skip the pads' work."""
     tokens = batch["tokens"]
     x = _embed_tokens(params, cfg, tokens, compute_dtype)
     if cfg.modality == "vlm" and "vision_embeds" in batch:
@@ -527,9 +536,13 @@ def prefill(params, cfg: ModelConfig, rules: Rules, batch, cache,
     positions = batch.get("positions")
     if positions is None:
         positions = _default_positions(cfg, B, S)
+    token_mask = None
+    if last_index is not None and cfg.has_moe:
+        token_mask = jnp.arange(S)[None] <= jnp.asarray(last_index)
     ctx = Ctx(rules=rules, mode="prefill", positions=positions,
               cache_index=jnp.zeros((), jnp.int32),
-              compute_dtype=compute_dtype, cost_exact=cost_exact)
+              compute_dtype=compute_dtype, cost_exact=cost_exact,
+              token_mask=token_mask)
     if cfg.encoder is not None:
         ctx.enc_out = run_encoder(params, batch["frames"].astype(
             compute_dtype), cfg, ctx)
@@ -550,9 +563,13 @@ def prefill(params, cfg: ModelConfig, rules: Rules, batch, cache,
 
 def decode_step(params, cfg: ModelConfig, rules: Rules, token, cache,
                 index, compute_dtype=jnp.bfloat16,
-                cost_exact: bool = False, unroll: bool = False):
+                cost_exact: bool = False, unroll: bool = False,
+                active=None, counts: bool = False):
     """One decode step. token [B,1] int32; index scalar int32 (fill point).
-    Returns (new_cache, logits [B,V])."""
+    Returns (new_cache, logits [B,V]), and with `counts` the layers'
+    counts of their work ({name: int32 scalar}). `active` [B] bool marks
+    the slots that decode: the MoE layers compute and count only
+    theirs."""
     B = token.shape[0]
     x = _embed_tokens(params, cfg, token, compute_dtype)
     idx = jnp.asarray(index)
@@ -562,11 +579,14 @@ def decode_step(params, cfg: ModelConfig, rules: Rules, token, cache,
            for _, _, _, s in cfg.sublayers()):
         pos = jnp.broadcast_to(pos[None], (3, B, 1))
     ctx = Ctx(rules=rules, mode="decode", positions=pos, cache_index=index,
-              compute_dtype=compute_dtype)
+              compute_dtype=compute_dtype,
+              token_mask=None if active is None else active[:, None])
     x = rules.constrain(x, "batch", None, "res_embed")
     x, new_cache, _ = run_stack(params, x, cfg, ctx, caches=cache,
                                 unroll=unroll)
     with jax.named_scope("head"):
         x = apply_norm(params["final_norm"], x, cfg.norm, cfg.norm_eps)
         logits = _logits(params, cfg, x, ctx)[:, 0]
+    if counts:
+        return new_cache, logits, ctx.counts
     return new_cache, logits

@@ -63,7 +63,11 @@ trace is wanted) mark each phase of `admit`, `step`, `pause` and
 idle gap under the host work that caused it. `counters["d2h_bytes"]`
 counts, always, the bytes the engine copies from the device to the
 host: the step's logits, the first token's logits and the KV blocks
-that pauses and checkpoints read.
+that pauses and checkpoints read. A model with MoE layers also counts,
+over decode steps and layers, `moe_experts_reached` (held experts that
+a decoding token was routed to) and `moe_pairs` (token-expert pairs
+computed): the decode program adds them to an accumulator it carries on
+the device, read only when `counters` is.
 """
 from __future__ import annotations
 
@@ -148,6 +152,9 @@ def _splice_block(cache, blk, slot):
     return {"groups": groups, "tail": tail}
 
 
+MOE_COUNTERS = ("moe_experts_reached", "moe_pairs")
+
+
 class DecodeEngine:
     def __init__(self, cfg: ModelConfig, params, rules: Rules, *,
                  max_slots: int = 4, max_len: int = 256,
@@ -226,7 +233,12 @@ class DecodeEngine:
             spec.kind in ("attn", "ffn", "moe")
             for *_ignored, spec in cfg.sublayers())
         self.jit_stats = {"prefill_traces": 0}
-        self.counters = {"d2h_bytes": 0}
+        self._counters = {"d2h_bytes": 0}
+        # the MoE layers' counts, summed on the device by the decode step
+        self._moe = None
+        if cfg.has_moe:
+            self._moe = jax.device_put(
+                {k: np.int32(0) for k in MOE_COUNTERS}, placement)
 
         def prefill(params, batch, cache, last_index=None):
             self.jit_stats["prefill_traces"] += 1
@@ -238,10 +250,30 @@ class DecodeEngine:
             return model_lib.decode_step(params, cfg, rules, token, cache,
                                          index, compute_dtype=compute_dtype)
 
+        def decode_step_moe(params, token, cache, index, active, moe):
+            new_cache, logits, got = model_lib.decode_step(
+                params, cfg, rules, token, cache, index,
+                compute_dtype=compute_dtype, active=active, counts=True)
+            return new_cache, logits, {k: moe[k] + got[k] for k in moe}
+
         self._prefill = jax.jit(prefill)
         # the step consumes the cache it is given: written in place, the
         # new K/V rows are all it moves (`step` rebinds `self.cache`)
-        self._decode = jax.jit(decode_step, donate_argnames="cache")
+        if self._moe is None:
+            self._decode = jax.jit(decode_step, donate_argnames="cache")
+        else:
+            self._decode = jax.jit(decode_step_moe,
+                                   donate_argnames=("cache", "moe"))
+
+    @property
+    def counters(self) -> Dict[str, int]:
+        """The engine's counts so far (a copy); the MoE counts are
+        fetched from the device."""
+        out = dict(self._counters)
+        if self._moe is not None:
+            out.update({k: int(v) for k, v in
+                        jax.device_get(self._moe).items()})
+        return out
 
     # -------------------------------------------------------- observability
     def _trace_session(self, name: str, rid: str, flow: str = "",
@@ -314,7 +346,7 @@ class DecodeEngine:
                 with span("engine.first_token"):
                     row = np.asarray(logits[0])
                     first = int(np.argmax(row))
-                self.counters["d2h_bytes"] += row.nbytes
+                self._counters["d2h_bytes"] += row.nbytes
             req.generated.append(first)
             self.last_token[slot] = first
             self._trace_session("admit", req.rid, flow="s", slot=slot,
@@ -336,7 +368,7 @@ class DecodeEngine:
             "tail": jax.tree.map(lambda a: np.asarray(a[slot]),
                                  self.cache["tail"]),
         }
-        self.counters["d2h_bytes"] += sum(
+        self._counters["d2h_bytes"] += sum(
             a.nbytes for a in jax.tree.leaves(blk))
         return blk
 
@@ -581,16 +613,21 @@ class DecodeEngine:
         with span("engine.step", step=self.steps):
             with span("engine.launch"):
                 idx = jnp.asarray(self.lengths)
-                self.cache, logits = self._decode(
+                moe = {} if self._moe is None else {
+                    "active": jnp.asarray(act), "moe": self._moe}
+                out = self._decode(
                     self.params, token=jnp.asarray(self.last_token[:, None]),
-                    cache=self.cache, index=idx)
+                    cache=self.cache, index=idx, **moe)
+                self.cache, logits = out[:2]
+                if moe:
+                    self._moe = out[2]
             self.steps += 1
             if self.step_time:
                 # modeled decode compute overlaps in-flight KV transfers
                 self.store.runtime.advance(self.step_time)
             with span("engine.fetch"):
                 host = np.asarray(logits)
-            self.counters["d2h_bytes"] += host.nbytes
+            self._counters["d2h_bytes"] += host.nbytes
             with span("engine.sample"):
                 nxt = np.argmax(host, axis=-1).astype(np.int32)
                 self.last_token = np.where(act, nxt, self.last_token)

@@ -1,7 +1,9 @@
 """Compiles the main path for one TPU v5e chip that is described, not
 attached: the case-study kernels at the smoke widths and the served
-decode step of mistral-nemo-12b at published widths (8 of 40 layers);
-and the decode step on the described 2x2 mesh under the serve rules.
+decode step of mistral-nemo-12b at published widths (8 of 40 layers)
+and of qwen3-moe-235b-a22b's served share (12 layers, 8 of 128
+experts); and the decode step on the described 2x2 mesh under the serve
+rules.
 
 Nothing runs; the TPU compiler refuses here what it would refuse on the
 chip (block tiling, scoped VMEM, dtypes, device memory). The topology
@@ -123,6 +125,54 @@ def test_served_decode_step_writes_its_cache_in_place_on_v5e(topo,
     mem = c.memory_analysis()
     assert mem.alias_size_in_bytes == cache_bytes
     assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+
+
+def test_served_moe_step_compiles_for_v5e(topo, one_chip):
+    """qwen3-moe-235b-a22b at published widths as one expert-parallel
+    rank (12 layers, experts [0, 8) of 128 held): the donated decode
+    step at 24 slots x 4096 bf16 KV aliases its cache and runs its
+    experts as grouped-matmul kernels, and a prefill of the largest
+    prompt bucket fits beside the weights and two caches."""
+    from repro.models.config import MoeSpec
+    base = get_config("qwen3-moe-235b-a22b")
+    cfg = dataclasses.replace(base, n_groups=12, pattern=tuple(
+        tuple(dataclasses.replace(s, n_held=8)
+              if isinstance(s, MoeSpec) else s for s in layer)
+        for layer in base.pattern))
+    rules = single_device_rules(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                               M.init_params(k, cfg)[0]),
+        jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(
+        lambda: M.init_cache(cfg, 24, 4096, dtype=jnp.bfloat16)))
+    step = jax.jit(functools.partial(
+        M.decode_step, cfg=cfg, rules=rules, compute_dtype=jnp.bfloat16,
+        counts=True), donate_argnames="cache")
+    c = step.lower(params, token=_spec(one_chip, (24, 1), jnp.int32),
+                   cache=cache, index=_spec(one_chip, (24,), jnp.int32),
+                   active=_spec(one_chip, (24,), jnp.bool_)).compile()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    assert "ragged-dot" in c.as_text()
+    pre = jax.jit(functools.partial(
+        M.prefill, cfg=cfg, rules=rules, compute_dtype=jnp.bfloat16))
+    c = pre.lower(params,
+                  batch={"tokens": _spec(one_chip, (1, 2048), jnp.int32)},
+                  cache=on_chip(jax.eval_shape(lambda: M.init_cache(
+                      cfg, 1, 4096, dtype=jnp.bfloat16))),
+                  last_index=_spec(one_chip, (), jnp.int32)).compile()
+    mem = c.memory_analysis()
+    weights = sum(a.size * 2 for a in jax.tree.leaves(params))
+    assert weights + 2 * cache_bytes + mem.temp_size_in_bytes < 14e9
 
 
 # (config at published widths, mesh (data, model)): which cache axes the
